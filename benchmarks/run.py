@@ -32,6 +32,7 @@ from repro.core import opt, partition, rbl, rctc, rhal, rimfs
 from repro.core.executor import Executor
 from repro.core.rcb import Op, RCBProgram
 from repro.core.rtpm import Platform
+from repro.launch import compile_cache
 from repro.models import resnet as rn
 
 ROWS: list[str] = []
@@ -1120,6 +1121,7 @@ def main() -> None:
                          "fresh results without losing the committed "
                          "baseline)")
     args = ap.parse_args()
+    compile_cache.enable()
     quick = args.quick or args.smoke
     try:
         with open(args.baseline) as f:

@@ -50,3 +50,5 @@ try:
     client.close()
 finally:
     server.stop()
+if ref_match != n_requests:
+    sys.exit(1)

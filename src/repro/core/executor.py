@@ -381,6 +381,33 @@ class Executor:
                 return b
         return self.BATCH_BUCKETS[-1]
 
+    def _chunks(self, n: int, max_bucket: Optional[int]) -> list:
+        """``(take, bucket)`` per chunk for ``n`` requests: full
+        largest-bucket chunks first, then the remainder padded up to the
+        smallest covering bucket. A non-ladder ``max_bucket`` stages its
+        own chunk size rather than padding past the caller's clamp."""
+        top = self.BATCH_BUCKETS[-1] if max_bucket is None \
+            else max(1, min(max_bucket, self.BATCH_BUCKETS[-1]))
+        out = []
+        while n > 0:
+            take = min(top, n)
+            out.append((take, min(self._bucket_for(take), top)))
+            n -= take
+        return out
+
+    def prepare_batched(self, bound: BoundProgram, n: int) -> list:
+        """Compile every bucket ``run_batched`` would stage for ``n``
+        requests, without running anything. A caller that times the
+        dispatch (the server's watchdog) calls this first, so a cold
+        bucket's compile never counts as execution. Returns the buckets,
+        none for a program that is not batchable."""
+        if not linker_mod.batch_analysis(bound).batchable:
+            return []
+        buckets = [bucket for _, bucket in self._chunks(n, None)]
+        for bucket in buckets:
+            self._batched_callable(bound, bucket)
+        return buckets
+
     def run_batched(self, bound: BoundProgram, inputs_list,
                     rimfs=None, max_bucket: Optional[int] = None) -> list:
         """Execute one program over a batch of independent requests.
@@ -429,17 +456,10 @@ class Executor:
                       if t.kind == "input"),
                 self.weights_from(bound))
         _, input_syms, weights = prep
-        top = self.BATCH_BUCKETS[-1] if max_bucket is None \
-            else max(1, min(max_bucket, self.BATCH_BUCKETS[-1]))
         # phase 1: stack + dispatch every chunk (no sync anywhere)
         pending: list = []                 # (pos, take, {sym: device out})
         pos = 0
-        while pos < len(reqs):
-            rem = len(reqs) - pos
-            take = top if rem >= top else rem
-            # a non-ladder max_bucket stages its own chunk size rather
-            # than padding past the caller's clamp
-            bucket = min(self._bucket_for(take), top)
+        for take, bucket in self._chunks(len(reqs), max_bucket):
             chunk = reqs[pos:pos + take]
             stacked = {}
             for sym in input_syms:

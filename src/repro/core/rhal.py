@@ -57,16 +57,6 @@ ARENA_ALIGN = 128                 # matches rimfs.ALIGN: one DMA lane quantum
 DEFAULT_ARENA_BYTES = 1 << 30     # modeled slab size for the eager driver
 
 
-@dataclasses.dataclass(frozen=True)
-class DeviceConstants:
-    """Roofline constants for the target device (TPU v5e defaults)."""
-    name: str = "tpu_v5e"
-    peak_flops_bf16: float = 197e12          # FLOP/s per chip
-    hbm_bandwidth: float = 819e9             # B/s per chip
-    ici_link_bandwidth: float = 50e9         # B/s per link
-    hbm_bytes: float = 16e9
-
-
 class ArenaError(RuntimeError):
     pass
 
@@ -245,7 +235,6 @@ class HalDriver:
     fence: Callable[[list], None]
     poll: Callable[[Any], bool]
     donate: Callable[[Any], Any]
-    constants: DeviceConstants = DeviceConstants()
     stats: dict = dataclasses.field(default_factory=dict)
     # Optional compiled-dispatch slot (core/linker.py): resolve one opcode
     # to a specialized positional handler ``fn(*srcs) -> out`` ONCE at link
@@ -474,7 +463,7 @@ def make_eager_driver(device: Optional[jax.Device] = None,
         fn = oplib.lookup(op)
         return jax.jit(lambda *srcs: fn(srcs, attrs))
 
-    d = HalDriver("eager_cpu", alloc, free, bind_const, initiate_dma,
+    d = HalDriver("eager", alloc, free, bind_const, initiate_dma,
                   wait_dma, dispatch_compute, collective, fence, poll, donate,
                   link_compute=link_compute, dma_async=dma_async,
                   dma_wait=dma_wait_, dma_async_batch=dma_async_batch,

@@ -24,17 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new API (``check_vma``) when
-    present, ``jax.experimental.shard_map`` (``check_rep``) otherwise."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _psum(x, axis):
     return jax.lax.psum(x, axis_name=axis)
 
@@ -45,8 +34,7 @@ def compressed_psum(grad: jax.Array, axis: str, method: str = "bf16",
 
     Returns (reduced_grad fp32, new_error). Call inside shard_map.
     """
-    n = (jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, axis))    # jax 0.4.x compat
+    n = jax.lax.axis_size(axis)
     g = grad.astype(jnp.float32)
     if method == "none":
         return _psum(g, axis) / n, error
@@ -89,9 +77,9 @@ def make_dp_train_step(loss_fn, optimizer_update, mesh, axis: str = "data",
     on `axis`. Demonstrates the shard_map composition used between pods.
     """
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(axis), P()),
-        out_specs=(P(), P()))
+        out_specs=(P(), P()), check_vma=False)
     def step(params, batch, errors):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         grads, new_errors = compressed_psum_tree(grads, axis, method, errors)

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.collectives import shard_map_compat
 
 
 def pipeline_forward(stage_fn, mesh, axis: str = "stage"):
@@ -31,9 +30,9 @@ def pipeline_forward(stage_fn, mesh, axis: str = "stage"):
     n_stage = mesh.shape[axis]
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     def run(stage_params, mbs):
         params_local = jax.tree.map(lambda a: a[0], stage_params)
         idx = jax.lax.axis_index(axis)

@@ -8,14 +8,15 @@ sites — eager model code, GRAPH_EXEC artifacts, and linked RCB kernel ops
 their implementation from here, so each hot loop has exactly one
 implementation.
 
-Fallback ladder (DESIGN.md §13):
+Dispatch (DESIGN.md §13):
   1. explicit ``impl`` override (``"pallas"`` | ``"ref"``) from op attrs
      or a keyword — tests, debugging, A/B rows;
   2. pallas with ``interpret`` resolved per call site OUTSIDE any trace
      (kernels/common.resolve_interpret: compiled on TPU, interpret-mode
      elsewhere);
-  3. the ``ref.py`` oracle when the pallas toolchain is unavailable
-     (import failure is caught at module load and remembered).
+  3. the ``ref.py`` oracle only where a spec's ``accepts`` refuses the
+     shapes (non-causal attention on ragged tiles). ``stats`` counts
+     every call under the implementation that actually ran.
 
 Autotune: ``autotune()`` sweeps the spec's candidate block sizes on the
 live backend and records the winner per (kernel, shape-sig, dtype,
@@ -37,25 +38,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import rimfs as rimfs_mod
+from repro.kernels.flash_attention import ops as _fa_ops
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.int8_matmul import ops as _im_ops
 from repro.kernels.int8_matmul.ref import int8_matmul_ref
+from repro.kernels.ssm_scan import ops as _ss_ops
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
+from repro.kernels.wkv6 import ops as _wk_ops
 from repro.kernels.wkv6.ref import wkv6_ref
 
 AUTOTUNE_FILE = "kernels/autotune.json"
 KERNEL_NAMES = ("attention", "matmul_int8", "ssm_scan", "wkv6")
-
-# The pallas wrappers are gated: a missing/broken pallas toolchain demotes
-# every kernel to its ref oracle instead of failing at import.
-try:
-    from repro.kernels.flash_attention import ops as _fa_ops
-    from repro.kernels.int8_matmul import ops as _im_ops
-    from repro.kernels.ssm_scan import ops as _ss_ops
-    from repro.kernels.wkv6 import ops as _wk_ops
-    PALLAS_IMPORT_ERROR: Optional[BaseException] = None
-except Exception as e:  # pragma: no cover — pallas toolchain absent
-    _fa_ops = _im_ops = _ss_ops = _wk_ops = None
-    PALLAS_IMPORT_ERROR = e
 
 
 def _divisor_leq(dim: int, cap: int) -> int:
@@ -92,15 +85,21 @@ def _attention_ref_bshd(q, k, v, *, causal: bool = True):
 
 
 def _attention_pallas(q, k, v, *, params: dict, causal: bool = True):
-    s, sk = q.shape[1], k.shape[1]
-    bq = min(int(params["block_q"]), s)
-    bk = min(int(params["block_k"]), sk)
-    if not causal and (s % bq or sk % bk):
-        # non-causal + ragged tiles would fold padded keys into the
-        # softmax; causal masking already excludes the tail (kpos > qpos)
-        return _attention_ref_bshd(q, k, v, causal=causal)
     return _fa_ops.flash_attention(q, k, v, causal=causal,
-                                   block_q=bq, block_k=bk)
+                                   block_q=params["block_q"],
+                                   block_k=params["block_k"])
+
+
+def _attention_accepts(params: dict, args, kwargs: dict) -> bool:
+    """Non-causal attention on ragged tiles would fold padded keys into
+    the softmax; causal masking already excludes the tail (kpos > qpos)."""
+    q, k = args[0], args[1]
+    return kwargs.get("causal", True) or not (
+        q.shape[1] % params["block_q"] or k.shape[1] % params["block_k"])
+
+
+def _always(params: dict, args, kwargs: dict) -> bool:
+    return True
 
 
 def _attention_space(q, k, v):
@@ -209,30 +208,16 @@ def _wkv6_normalize(params: dict, args) -> dict:
 # sequences and normalizes block sizes itself, so only the semantic
 # shape/dtype rules apply here.
 
-def _contract_attention(q, k, v):
-    if _fa_ops is not None:
-        _fa_ops.check_contract(q, k, v)
-        return
-    if q.ndim != 4 or k.shape != v.shape or q.shape[1] == 0:
-        raise ValueError("flash_attention: bad operand shapes")
-    if k.shape[2] == 0 or q.shape[2] % k.shape[2] != 0:
-        raise ValueError("flash_attention: GQA grouping requires "
-                         "num_heads % num_kv_heads == 0")
-
-
 def _contract_matmul_int8(x, w, scale):
-    if _im_ops is not None:
-        _im_ops.check_contract(x, w, scale, block_m=1, block_n=1, block_k=1)
+    _im_ops.check_contract(x, w, scale, block_m=1, block_n=1, block_k=1)
 
 
 def _contract_ssm_scan(da, bx, c):
-    if _ss_ops is not None:
-        _ss_ops.check_contract(da, bx, c, chunk=1, d_block=1)
+    _ss_ops.check_contract(da, bx, c, chunk=1, d_block=1)
 
 
 def _contract_wkv6(r, k, v, lw, u):
-    if _wk_ops is not None:
-        _wk_ops.check_contract(r, k, v, lw, u, chunk=1)
+    _wk_ops.check_contract(r, k, v, lw, u, chunk=1)
 
 
 # ---------------------------------------------------------------------------
@@ -243,12 +228,13 @@ def _contract_wkv6(r, k, v, lw, u):
 class KernelSpec:
     """One kernel: pallas impl + ref oracle + contract + autotune space."""
     name: str
-    pallas: Optional[Callable]          # (*args, params=dict, **kw) -> out
+    pallas: Callable                    # (*args, params=dict, **kw) -> out
     ref: Callable                       # (*args, **kw) -> out
     contract: Callable                  # (*args) -> None or ValueError
     space: Callable                     # (*args) -> list[dict] candidates
     normalize: Callable                 # (params, args) -> valid params
     defaults: tuple                     # ((param, value), ...)
+    accepts: Callable = _always         # (params, args, kw) -> pallas ok?
 
 
 class KernelRegistry:
@@ -271,10 +257,6 @@ class KernelRegistry:
             raise KeyError(f"unknown kernel {name!r}; registered: "
                            f"{sorted(self.specs)}")
         return spec
-
-    def available(self, name: str) -> bool:
-        """True iff the pallas implementation imported successfully."""
-        return self.get(name).pallas is not None
 
     def _count(self, key: str, n: int = 1) -> None:
         self.stats[key] = self.stats.get(key, 0) + n
@@ -300,25 +282,23 @@ class KernelRegistry:
 
     def call(self, name: str, *args, impl: Optional[str] = None,
              params: Optional[dict] = None, **kwargs):
-        """Dispatch one kernel through the fallback ladder."""
+        """Dispatch one kernel: explicit ``impl`` first, then pallas
+        wherever the spec accepts the shapes, else the ref oracle."""
         spec = self.get(name)
         spec.contract(*args)
-        if impl == "ref" or spec.pallas is None:
-            if impl == "pallas" and spec.pallas is None:
-                raise RuntimeError(
-                    f"kernel {name!r}: pallas requested but unavailable "
-                    f"({PALLAS_IMPORT_ERROR!r})")
-            self._count(f"{name}_ref")
-            return spec.ref(*args, **kwargs)
-        if impl not in (None, "pallas"):
+        if impl not in (None, "pallas", "ref"):
             raise ValueError(f"kernel {name!r}: unknown impl {impl!r} "
                              f"(expected 'pallas' or 'ref')")
-        if params is None:
-            params = self.params_for(name, args, kwargs)
-        else:
-            params = spec.normalize(dict(params), args)
-        self._count(f"{name}_pallas")
-        return spec.pallas(*args, params=params, **kwargs)
+        if impl != "ref":
+            if params is None:
+                params = self.params_for(name, args, kwargs)
+            else:
+                params = spec.normalize(dict(params), args)
+            if spec.accepts(params, args, kwargs):
+                self._count(f"{name}_pallas")
+                return spec.pallas(*args, params=params, **kwargs)
+        self._count(f"{name}_ref")
+        return spec.ref(*args, **kwargs)
 
     # ------------------------------------------------------------- autotune
     def autotune(self, name: str, *args, **kwargs):
@@ -332,11 +312,6 @@ class KernelRegistry:
         if hit is not None:
             self._count("autotune_hit")
             return dict(hit["params"]), 0
-        if spec.pallas is None:
-            params = spec.normalize(dict(spec.defaults), args)
-            self.winners[key] = {"params": params, "us": None,
-                                 "source": "default"}
-            return params, 0
         best, best_t = None, None
         trials = 0
         for cand in spec.space(*args):
@@ -392,28 +367,29 @@ def _build_default_registry() -> KernelRegistry:
     reg = KernelRegistry()
     reg.register(KernelSpec(
         "attention",
-        _attention_pallas if _fa_ops is not None else None,
+        _attention_pallas,
         _attention_ref_bshd,
-        _contract_attention,
+        _fa_ops.check_contract,
         _attention_space, _attention_normalize,
-        (("block_q", 128), ("block_k", 128))))
+        (("block_q", 128), ("block_k", 128)),
+        _attention_accepts))
     reg.register(KernelSpec(
         "matmul_int8",
-        _matmul_int8_pallas if _im_ops is not None else None,
+        _matmul_int8_pallas,
         _matmul_int8_ref,
         _contract_matmul_int8,
         _matmul_int8_space, _matmul_int8_normalize,
         (("block_m", 128), ("block_n", 128), ("block_k", 128))))
     reg.register(KernelSpec(
         "ssm_scan",
-        _ssm_scan_pallas if _ss_ops is not None else None,
+        _ssm_scan_pallas,
         ssm_scan_ref,
         _contract_ssm_scan,
         _ssm_scan_space, _ssm_scan_normalize,
         (("chunk", 16), ("d_block", 256))))
     reg.register(KernelSpec(
         "wkv6",
-        _wkv6_pallas if _wk_ops is not None else None,
+        _wkv6_pallas,
         _wkv6_ref_bthk,
         _contract_wkv6,
         _wkv6_space, _wkv6_normalize,
@@ -430,10 +406,6 @@ REGISTRY = _build_default_registry()
 
 def get(name: str) -> KernelSpec:
     return REGISTRY.get(name)
-
-
-def available(name: str) -> bool:
-    return REGISTRY.available(name)
 
 
 def call(name: str, *args, **kwargs):

@@ -34,18 +34,23 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *,
     lw = lw_ref[0].astype(jnp.float32)          # log-decay, <= 0
     u = u_ref[0].astype(jnp.float32)            # (1, K) bonus
 
-    C = chunk
-    p = jnp.cumsum(lw, axis=0)                  # inclusive
+    C, K = chunk, lw.shape[-1]
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum lowering)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+           <= jax.lax.broadcasted_iota(jnp.int32, (C, C), 0))
+    p = jax.lax.dot(tri.astype(jnp.float32), lw,
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
     pprev = p - lw                              # exclusive (p_{t-1})
 
     # intra-chunk: att[t,j] = sum_i r[t,i] k[j,i] exp(pprev[t,i]-p[j,i]), j<t
     diff = pprev[:, None, :] - p[None, :, :]    # (C,C,K), <=0 for j<=t-1
-    tmask = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    jmask = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    mask = (jmask < tmask)[:, :, None]
+    # strictly causal mask, built 3-D (Mosaic cannot reshape an i1 vector)
+    mask = (jax.lax.broadcasted_iota(jnp.int32, (C, C, K), 1)
+            < jax.lax.broadcasted_iota(jnp.int32, (C, C, K), 0))
     e = jnp.where(mask, jnp.exp(jnp.where(mask, diff, 0.0)), 0.0)
-    att = jnp.einsum("ti,ji,tji->tj", r, k, e,
-                     preferred_element_type=jnp.float32)
+    att = jnp.sum(r[:, None, :] * k[None, :, :] * e, axis=-1)   # (C, C)
     y = jax.lax.dot(att, v, preferred_element_type=jnp.float32)
 
     # diagonal bonus: y[t] += (r[t] . (u*k[t])) v[t]
@@ -58,8 +63,10 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *,
                         preferred_element_type=jnp.float32)
 
     # state update: S' = exp(p[-1]) * S + sum_t (k[t]*exp(p[-1]-p[t])) v[t]^T
-    kd = k * jnp.exp(p[-1:] - p)
-    s_scr[...] = jnp.exp(p[-1])[:, None] * s + jax.lax.dot(
+    # (static slice: an integer index lowers to an unsupported dynamic_slice)
+    p_last = jax.lax.slice_in_dim(p, C - 1, C, axis=0)          # (1, K)
+    kd = k * jnp.exp(p_last - p)
+    s_scr[...] = jnp.exp(p_last).T * s + jax.lax.dot(
         kd.T, v, preferred_element_type=jnp.float32)
 
     o_ref[0] = y.astype(o_ref.dtype)
@@ -67,7 +74,10 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *,
 
 def wkv6_bhtk(r, k, v, lw, u, *, chunk: int = 64,
               interpret: bool = False) -> jax.Array:
-    """r/k/v/lw: (BH, T, K); u: (BH, K). Returns y (BH, T, K)."""
+    """r/k/v/lw: (BH, T, K); u: (BH, 1, K). Returns y (BH, T, K).
+
+    ``u`` carries a unit middle axis so its block's last two dims equal
+    the array's, as the TPU compiler requires of a (1, K) tile."""
     bh, t, kk = r.shape
     chunk = min(chunk, t)
     assert t % chunk == 0, (t, chunk)
@@ -81,7 +91,7 @@ def wkv6_bhtk(r, k, v, lw, u, *, chunk: int = 64,
             pl.BlockSpec((1, chunk, kk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, kk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, kk), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, kk), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, kk), lambda b, c: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, kk), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, kk), r.dtype),

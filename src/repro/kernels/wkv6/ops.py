@@ -22,7 +22,7 @@ def _wkv6_jit(r, k, v, lw, u, *, chunk: int, interpret: bool) -> jax.Array:
     def fold(a):
         return a.transpose(0, 2, 1, 3).reshape(b * h, t, kk)
 
-    u_full = jnp.broadcast_to(u[None], (b, h, kk)).reshape(b * h, kk)
+    u_full = jnp.broadcast_to(u[None], (b, h, kk)).reshape(b * h, 1, kk)
     y = wkv6_bhtk(fold(r), fold(k), fold(v), fold(lw), u_full,
                   chunk=chunk, interpret=interpret)
     return y.reshape(b, h, t, kk).transpose(0, 2, 1, 3)

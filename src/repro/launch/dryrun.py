@@ -1,11 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay the first statements in this module — jax locks
-the device count on first init, and the production meshes need 512 host
-placeholder devices. (Smoke tests and benchmarks import repro normally and
-see 1 device; only this entrypoint forces 512.)
+The three lines above MUST stay the first statements in this module — jax
+locks the device count on first init, and the production meshes need 512
+host placeholder devices. (Smoke tests and benchmarks import repro normally
+and see 1 device; only this entrypoint forces 512.) The dry-run is pinned to
+the CPU platform: on a TPU host it would otherwise take the chip and find
+too few devices, and the ``--all`` children it spawns inherit the pin, so
+none of them contends for the chip either.
 
 Per cell we record: compile wall-time, ``memory_analysis()`` (proves the
 per-device footprint), ``cost_analysis()`` (FLOPs / bytes for the roofline),

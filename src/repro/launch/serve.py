@@ -1,18 +1,22 @@
 """Serving driver: network-attached inference service (the paper's mode).
 
-Starts the CRC-framed socket server, provisions the ResNet-18 case study
-(or an LM engine with --lm), fires batched client requests at it —
-optionally from several concurrent connections, each pipelining v2
-request-id frames — and reports latency CV + dispatcher telemetry.
+Starts the CRC-framed socket server, provisions a model over the socket
+and fires client requests at it -- optionally from several concurrent
+connections, each pipelining v2 request-id frames -- then reports
+latency and dispatcher telemetry. ``--model`` names the configuration:
+``resnet18`` (224x224, widths 64-512, 1000 classes) serves the RCB
+program through the batch window; an LM configuration such as
+``qwen2-1.5b`` serves ``PagedServingEngine`` behind the same server.
+``-smoke`` variants are the reduced CPU sizes.
 
-  PYTHONPATH=src python -m repro.launch.serve --requests 64
-  PYTHONPATH=src python -m repro.launch.serve --requests 64 --clients 4
-  PYTHONPATH=src python -m repro.launch.serve --lm --requests 8
+  PYTHONPATH=src python -m repro.launch.serve --model resnet18-smoke
+  PYTHONPATH=src python -m repro.launch.serve --model resnet18 --clients 4
+  PYTHONPATH=src python -m repro.launch.serve --model qwen2-1.5b-smoke
   PYTHONPATH=src python -m repro.launch.serve --fleet --requests 48
 
 --fleet runs the elastic-operations demo: a FleetController scales the
 live tile mesh up and back down, hot-swaps the weight image (probe +
-atomic flip), and survives a tile-group kill — all under the same
+atomic flip), and survives a tile-group kill -- all under the same
 client traffic, with every response checked against a reference.
 """
 from __future__ import annotations
@@ -27,62 +31,107 @@ import jax
 
 from repro.configs import get_config
 from repro.configs.resnet18 import CONFIG as RESNET
+from repro.configs.resnet18 import ResNetConfig
 from repro.core import rctc
+from repro.launch import compile_cache
 from repro.models import resnet as rn
 from repro.models import transformer as tf
 from repro.models.common import init_params
-from repro.serving.engine import Request, ServingEngine
-from repro.serving.scheduler import DeadlineScheduler
+from repro.serving.paged_engine import PagedServingEngine
 from repro.serving.server import Client, InferenceServer
 
 
-def serve_resnet(requests: int, batch: int, clients: int,
-                 pipeline: int, batch_window: int = 8) -> None:
-    cfg = RESNET.smoke()
-    params = rn.init_resnet(jax.random.PRNGKey(0), cfg)
+def model_config(name: str):
+    """``resnet18[-smoke]`` or any registered LM configuration name."""
+    if name.split("-")[0] == RESNET.name:
+        return RESNET.smoke() if name.endswith("-smoke") else RESNET
+    return get_config(name)
+
+
+def _run_threads(target, n: int) -> None:
+    """Run ``target(i)`` on ``n`` threads; re-raise the first failure."""
+    errors: list = []
+
+    def guarded(i: int) -> None:
+        try:
+            target(i)
+        except Exception as e:          # re-raised on the calling thread
+            errors.append(e)
+
+    threads = [threading.Thread(target=guarded, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    if errors:
+        raise errors[0]
+
+
+# ------------------------------------------------------------------ resnet
+def start_resnet(cfg: ResNetConfig, seed: int = 0, batch: int = 4,
+                 batch_window: int = 8):
+    """Initialise ResNet-18 from ``seed``, lower it to an RCB program, start
+    a server and PROVISION the image and program over the socket. Returns
+    ``(params, server, client)``; ``params`` are the unfolded weights."""
+    params = rn.init_resnet(jax.random.PRNGKey(seed), cfg)
     prog, image = rctc.compile_resnet18(cfg, rn.fold_bn(params),
                                         batch=batch)
     server = InferenceServer(batch_window=batch_window)
-    addr = server.start()
-    print(f"[serve] listening on {addr}")
     try:
-        c0 = Client(addr)
-        print("[serve] provision:", c0.provision(image, prog.encode()))
-        # distribute --requests exactly: first `requests % clients`
-        # connections take one extra
-        shares = [requests // clients + (1 if c < requests % clients else 0)
-                  for c in range(clients)]
+        client = Client(server.start())
+        client.provision(image, prog.encode())
+    except Exception:
+        server.stop()
+        raise
+    return params, server, client
 
-        def run_client(cid: int, counts: list) -> None:
-            client = c0 if cid == 0 else Client(addr)
-            rng = np.random.RandomState(cid)
-            per_client = shares[cid]
-            done = 0
-            try:
-                for _ in range(0, per_client, pipeline):
-                    rids = []
-                    for _ in range(min(pipeline, per_client - done)):
-                        x = rng.rand(batch, cfg.image_size, cfg.image_size,
-                                     3).astype(np.float32)
-                        rids.append(client.infer_async(input=x))
-                    for rid in rids:
-                        client.result(rid)
-                        done += 1
-            finally:
-                counts[cid] = done
-                if cid != 0:
-                    client.close()
 
-        counts = [0] * clients
+def drive_resnet(addr: tuple, cfg: ResNetConfig, batch: int, requests: int,
+                 clients: int, pipeline: int, seed: int = 0) -> list:
+    """Send ``requests`` image batches from ``clients`` connections, each
+    keeping up to ``pipeline`` requests in flight. Returns one
+    ``(input, output)`` pair per reply; an error reply raises."""
+    # distribute the requests exactly: the first `requests % clients`
+    # connections take one extra
+    shares = [requests // clients + (1 if c < requests % clients else 0)
+              for c in range(clients)]
+    pairs: list = [[] for _ in range(clients)]
+
+    def run_client(cid: int) -> None:
+        client = Client(addr)
+        rng = np.random.RandomState(seed * 1000 + cid)
+        try:
+            left = shares[cid]
+            while left:
+                sent = []
+                for _ in range(min(pipeline, left)):
+                    x = rng.rand(batch, cfg.image_size, cfg.image_size,
+                                 3).astype(np.float32)
+                    sent.append((x, client.infer_async(input=x)))
+                for x, rid in sent:
+                    pairs[cid].append((x, client.result(rid)["output"]))
+                left -= len(sent)
+        finally:
+            client.close()
+
+    _run_threads(run_client, clients)
+    return [p for ps in pairs for p in ps]
+
+
+def serve_resnet(cfg: ResNetConfig, requests: int, batch: int, clients: int,
+                 pipeline: int, batch_window: int = 8, seed: int = 0) -> None:
+    t0 = time.perf_counter()
+    _, server, c0 = start_resnet(cfg, seed, batch, batch_window)
+    print(f"[serve] {cfg.name} provisioned on {server.address}")
+    try:
+        drive_resnet(server.address, cfg, batch, 1, 1, 1, seed)
+        buckets = server.warm()
+        print(f"[serve] set-up {time.perf_counter() - t0:.1f}s "
+              f"(batch buckets {buckets} compiled)")
         t0 = time.perf_counter()
-        threads = [threading.Thread(target=run_client, args=(cid, counts))
-                   for cid in range(clients)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
+        n = len(drive_resnet(server.address, cfg, batch, requests, clients,
+                             pipeline, seed + 1))
         dt = time.perf_counter() - t0
-        n = sum(counts)
         tel = c0.telemetry()
         srv = tel.get("serving", {})
         print(f"[serve] {n} requests x batch {batch} over {clients} "
@@ -161,40 +210,80 @@ def serve_fleet(requests: int, groups: int = 2, peak: int = 8) -> None:
         server.stop()
 
 
-def serve_lm(requests: int) -> None:
-    cfg = get_config("qwen2-1.5b-smoke")
-    params = init_params(jax.random.PRNGKey(0), tf.model_specs(cfg))
-    sched = DeadlineScheduler()
-    eng = ServingEngine(cfg, params, max_batch=4, max_seq=128,
-                        scheduler=sched)
-    rng = np.random.RandomState(0)
-    reqs = [Request(rid=i,
-                    prompt=rng.randint(0, cfg.vocab_size, (16,))
-                    .astype(np.int32), max_new=8)
-            for i in range(requests)]
+# ---------------------------------------------------------------------- lm
+def start_lm(cfg, seed: int = 0, max_batch: int = 4, max_seq: int = 512,
+             block_size: int = 16):
+    """Initialise the LM from ``seed`` and start ``PagedServingEngine``
+    behind ``InferenceServer``. Returns ``(params, engine, server)``."""
+    params = init_params(jax.random.PRNGKey(seed), tf.model_specs(cfg))
+    engine = PagedServingEngine(cfg, params, max_batch=max_batch,
+                                max_seq=max_seq, block_size=block_size)
+    server = InferenceServer(engine=engine)
+    server.start()
+    return params, engine, server
+
+
+def drive_lm(addr: tuple, prompts: list, max_new: int) -> list:
+    """Send every prompt on its own connection, all at once, through
+    ``Client.infer``. Returns the generated tokens per prompt, in order;
+    an error, busy or shed reply raises."""
+    outs: list = [None] * len(prompts)
+
+    def run_client(i: int) -> None:
+        client = Client(addr)
+        try:
+            outs[i] = client.infer(prompt=prompts[i],
+                                   max_new=max_new)["tokens"]
+        finally:
+            client.close()
+
+    _run_threads(run_client, len(prompts))
+    return outs
+
+
+def lm_prompts(cfg, n: int, length: int, seed: int = 0) -> list:
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, cfg.vocab_size, (length,)).astype(np.int32)
+            for _ in range(n)]
+
+
+def serve_lm(cfg, requests: int, prompt_len: int = 16, max_new: int = 8,
+             seed: int = 0) -> None:
     t0 = time.perf_counter()
-    for r in reqs:
-        eng.submit(r)
-    eng.run_until_drained()
-    dt = time.perf_counter() - t0
-    toks = sum(len(r.out_tokens) for r in reqs)
-    s = eng.telemetry.summary(warmup=2)
-    print(f"[serve-lm] {requests} prompts, {toks} tokens in {dt:.2f}s "
-          f"({toks/dt:.1f} tok/s); decode-step "
-          f"CV={s.get('cv_percent', 0):.2f}%; shed={sched.shed_count}")
+    _, engine, server = start_lm(cfg, seed)
+    print(f"[serve-lm] {cfg.name} set-up {time.perf_counter() - t0:.1f}s")
+    try:
+        prompts = lm_prompts(cfg, requests, prompt_len, seed)
+        t0 = time.perf_counter()
+        outs = drive_lm(server.address, prompts, max_new)
+        dt = time.perf_counter() - t0
+        toks = sum(len(o) for o in outs)
+        s = engine.telemetry.summary(warmup=2)
+        print(f"[serve-lm] {requests} prompts x {prompt_len} tokens, "
+              f"{toks} tokens in {dt:.2f}s ({toks/dt:.1f} tok/s, compile "
+              f"included); decode-step CV={s.get('cv_percent', 0):.2f}%; "
+              f"kv={engine.kv_stats()}")
+    finally:
+        server.stop()
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--model", default="resnet18-smoke",
+                    help="resnet18[-smoke] or an LM config, e.g. "
+                         "qwen2-1.5b[-smoke]")
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--requests", type=int, default=32)
-    ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--batch", type=int, default=4,
+                    help="resnet: images per request")
     ap.add_argument("--clients", type=int, default=1,
-                    help="concurrent client connections")
+                    help="resnet: concurrent client connections")
     ap.add_argument("--pipeline", type=int, default=4,
-                    help="in-flight pipelined requests per connection")
+                    help="resnet: in-flight pipelined requests per "
+                         "connection")
     ap.add_argument("--batch-window", type=int, default=8,
-                    help="dispatcher coalescing window (1 disables)")
-    ap.add_argument("--lm", action="store_true")
+                    help="resnet: dispatcher coalescing window "
+                         "(1 disables)")
     ap.add_argument("--fleet", action="store_true",
                     help="elastic fleet demo: scale cycle, hot swap, "
                          "kill/heal under traffic")
@@ -203,13 +292,17 @@ def main() -> None:
     ap.add_argument("--peak", type=int, default=8,
                     help="--fleet: scale-cycle peak mesh size")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.fleet:
         serve_fleet(args.requests, groups=args.groups, peak=args.peak)
-    elif args.lm:
-        serve_lm(args.requests)
+        return
+    cfg = model_config(args.model)
+    if isinstance(cfg, ResNetConfig):
+        serve_resnet(cfg, args.requests, args.batch, args.clients,
+                     args.pipeline, batch_window=args.batch_window,
+                     seed=args.seed)
     else:
-        serve_resnet(args.requests, args.batch, args.clients,
-                     args.pipeline, batch_window=args.batch_window)
+        serve_lm(cfg, args.requests, seed=args.seed)
 
 
 if __name__ == "__main__":

@@ -31,11 +31,20 @@ def attn_specs(cfg: ModelConfig) -> dict:
     L, d = cfg.num_layers, cfg.d_model
     H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     dt = cfg.dtype
+    # init_params takes shape[-2] as the fan-in; these projections contract
+    # over d (wq/wk/wv) and H*D (wo), so the scales below restore a
+    # 1/sqrt(true fan-in) std. Without them the q/k projections come out
+    # sqrt(d/H) times too large, every softmax is near one-hot, and a
+    # random 28-layer model's logits depend chaotically on rounding.
     p = {
-        "wq": ParamSpec((L, d, H, D), dt, ("layers", "fsdp", "heads", "head_dim")),
-        "wk": ParamSpec((L, d, Hkv, D), dt, ("layers", "fsdp", "kv_heads", "head_dim")),
-        "wv": ParamSpec((L, d, Hkv, D), dt, ("layers", "fsdp", "kv_heads", "head_dim")),
-        "wo": ParamSpec((L, H, D, d), dt, ("layers", "heads", "head_dim", "fsdp")),
+        "wq": ParamSpec((L, d, H, D), dt, ("layers", "fsdp", "heads", "head_dim"),
+                        "normal", (H / d) ** 0.5),
+        "wk": ParamSpec((L, d, Hkv, D), dt, ("layers", "fsdp", "kv_heads", "head_dim"),
+                        "normal", (Hkv / d) ** 0.5),
+        "wv": ParamSpec((L, d, Hkv, D), dt, ("layers", "fsdp", "kv_heads", "head_dim"),
+                        "normal", (Hkv / d) ** 0.5),
+        "wo": ParamSpec((L, H, D, d), dt, ("layers", "heads", "head_dim", "fsdp"),
+                        "normal", H ** -0.5),
     }
     if cfg.qkv_bias:
         p["bq"] = ParamSpec((L, H, D), dt, ("layers", "heads", "head_dim"), "zeros")
@@ -130,8 +139,8 @@ def _attend_full(cfg: ModelConfig, p: dict, q, k, v, out_dtype):
 
 
 def _attend_flash(cfg: ModelConfig, p: dict, q, k, v, out_dtype):
-    """Registry flash-attention path (pallas on TPU, interpret-mode on CPU,
-    ref fallback when pallas is unavailable) — the same handler the RCTC
+    """Registry flash-attention path (pallas on TPU, interpret-mode on
+    CPU) — the same handler the RCTC
     lowering dispatches as ``Op.ATTENTION``. Opt in via AEG_ATTN_IMPL=flash
     — the jnp path remains the default because interpret-mode pallas_call
     is slow to trace at dry-run scale."""

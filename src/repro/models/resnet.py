@@ -8,6 +8,7 @@ deployment path the paper demonstrates on the 4x7 AIE grid.
 """
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -19,8 +20,11 @@ from repro.models.common import ParamSpec, init_params
 
 
 def _conv_spec(kh, kw, cin, cout):
+    # He init over the true fan-in kh*kw*cin (init_params divides by cin
+    # only): without the taps every 3x3 conv triples the activation scale
+    # and the 224x224 logits reach ~1e8, a one-hot softmax.
     return ParamSpec((kh, kw, cin, cout), "float32", (None, None, None, None),
-                     "normal", 1.4)
+                     "normal", 1.4 / math.sqrt(kh * kw))
 
 
 def _bn_specs(c):

@@ -604,8 +604,11 @@ class InferenceServer:
             return
         wd = self._loop.watchdog
         self._executing = run
-        t0 = time.perf_counter()
         try:
+            # a cold bucket compiles here, before the watchdog is armed:
+            # compiling is set-up, not a hung execution
+            self.executor.prepare_batched(self._bound, len(run))
+            t0 = time.perf_counter()
             if wd is not None:
                 wd.arm(run)
             outs = self.executor.run_batched(
@@ -742,6 +745,22 @@ class InferenceServer:
         if box["error"] is not None:
             raise box["error"]
         return box["result"]
+
+    def warm(self, timeout: float = 1200.0) -> list:
+        """Compile, on the dispatcher, every batch bucket the coalescing
+        window can form for the provisioned program, so no served
+        dispatch compiles. A window coalesces runs of 2 or more (a solo
+        request takes the linked path), so bucket 1 is left out. Returns
+        the buckets compiled."""
+        def prepare() -> list:
+            if self._bound is None:
+                raise RuntimeError("not provisioned")
+            if not self._coalescible():
+                return []
+            return sorted({b for n in range(2, self.batch_window + 1)
+                           for b in self.executor.prepare_batched(
+                               self._bound, n)})
+        return self.run_on_dispatcher(prepare, timeout=timeout)
 
     def _pump_engine(self) -> bool:
         """ServiceLoop idle hook: one continuous-batching decode step,

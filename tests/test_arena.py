@@ -9,11 +9,7 @@ from repro.core import linker, rbl, rctc, rhal, rimfs
 from repro.core.executor import Executor
 from repro.core.rhal import ArenaError, DeviceArena
 
-try:
-    from hypothesis import given, settings, strategies as st
-    _HAS_HYPOTHESIS = True
-except ImportError:                       # optional test dependency
-    _HAS_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 
 # ---------------------------------------------------------------------------
@@ -244,55 +240,54 @@ def test_linked_pipeline_outputs_bit_identical(rng):
 
 
 # ---------------------------------------------------------------------------
-# Property tests (hypothesis optional, like PR 1)
+# Property tests (hypothesis)
 # ---------------------------------------------------------------------------
 
-if _HAS_HYPOTHESIS:
-    @given(st.lists(
-        st.tuples(st.booleans(), st.integers(1, 4096)),
-        min_size=1, max_size=60))
-    @settings(max_examples=60, deadline=None)
-    def test_property_arena_no_overlap_aligned(events):
-        """Random alloc/free sequences: live ranges never overlap, every
-        offset stays 128 B-aligned, and usage accounting balances."""
-        a = DeviceArena(1 << 20, debug=True)   # debug: invariants per op
-        live: list[int] = []
-        expect_in_use = 0
-        peak = 0
-        for is_alloc, size in events:
-            if is_alloc or not live:
-                try:
-                    off = a.alloc(size)
-                except ArenaError:
-                    continue
-                assert off % 128 == 0
-                live.append(off)
-                expect_in_use += _aligned(size)
-            else:
-                off = live.pop(size % len(live))
-                expect_in_use -= a._live[off]
-                a.free(off)
-            peak = max(peak, expect_in_use)
-            # no two live ranges overlap (debug check() also asserts this)
-            ranges = a.live_ranges()
-            for (o1, s1), (o2, s2) in zip(ranges, ranges[1:]):
-                assert o1 + s1 <= o2
-        assert a.bytes_in_use == expect_in_use
-        assert a.high_water == peak            # matches replayed peak
+@given(st.lists(
+    st.tuples(st.booleans(), st.integers(1, 4096)),
+    min_size=1, max_size=60))
+@settings(max_examples=60, deadline=None)
+def test_property_arena_no_overlap_aligned(events):
+    """Random alloc/free sequences: live ranges never overlap, every
+    offset stays 128 B-aligned, and usage accounting balances."""
+    a = DeviceArena(1 << 20, debug=True)   # debug: invariants per op
+    live: list[int] = []
+    expect_in_use = 0
+    peak = 0
+    for is_alloc, size in events:
+        if is_alloc or not live:
+            try:
+                off = a.alloc(size)
+            except ArenaError:
+                continue
+            assert off % 128 == 0
+            live.append(off)
+            expect_in_use += _aligned(size)
+        else:
+            off = live.pop(size % len(live))
+            expect_in_use -= a._live[off]
+            a.free(off)
+        peak = max(peak, expect_in_use)
+        # no two live ranges overlap (debug check() also asserts this)
+        ranges = a.live_ranges()
+        for (o1, s1), (o2, s2) in zip(ranges, ranges[1:]):
+            assert o1 + s1 <= o2
+    assert a.bytes_in_use == expect_in_use
+    assert a.high_water == peak            # matches replayed peak
 
-    @given(st.integers(1, 6), st.integers(1, 4))
-    @settings(max_examples=20, deadline=None)
-    def test_property_plan_peak_matches_closed_form(stages, scale):
-        """For the stage pipeline the precomputed high-water mark equals
-        the closed-form steady-state residency: weight + dev + acc."""
-        n = 8 * scale
-        prog = rctc.compile_dma_pipeline(stages, n)
-        rng = np.random.RandomState(0)
-        fs = rimfs.mount(rimfs.pack({"b": rng.randn(n, n)
-                                     .astype(np.float32)}))
-        ins = {f"in{i}": rng.randn(n, n).astype(np.float32)
-               for i in range(stages)}
-        plan = linker.plan_residency(rbl.bind(prog, rimfs=fs, inputs=ins))
-        # steady state: weight + one dev + one acc block, regardless of
-        # stage count — dead stage-k ranges are donated to stage k+1
-        assert plan.high_water == 3 * _aligned(n * n * 4)
+@given(st.integers(1, 6), st.integers(1, 4))
+@settings(max_examples=20, deadline=None)
+def test_property_plan_peak_matches_closed_form(stages, scale):
+    """For the stage pipeline the precomputed high-water mark equals
+    the closed-form steady-state residency: weight + dev + acc."""
+    n = 8 * scale
+    prog = rctc.compile_dma_pipeline(stages, n)
+    rng = np.random.RandomState(0)
+    fs = rimfs.mount(rimfs.pack({"b": rng.randn(n, n)
+                                 .astype(np.float32)}))
+    ins = {f"in{i}": rng.randn(n, n).astype(np.float32)
+           for i in range(stages)}
+    plan = linker.plan_residency(rbl.bind(prog, rimfs=fs, inputs=ins))
+    # steady state: weight + one dev + one acc block, regardless of
+    # stage count — dead stage-k ranges are donated to stage k+1
+    assert plan.high_water == 3 * _aligned(n * n * 4)

@@ -14,6 +14,7 @@ ResNet-18 case study.
 """
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 import jax
 
@@ -22,6 +23,23 @@ from repro.core.executor import Executor
 from repro.core.rcb import Op, RCB, RCBOp, RCBProgram, TensorDesc
 
 TILE_COUNTS = (1, 2, 4)
+
+# Batched and coalesced execution (``run_batched``, the server's batch
+# window) vmaps the program over a leading request axis. XLA may then sum
+# a convolution in another order than the serial program does, so a lane
+# agrees with its serial run to a few float32 ulp (max |diff| 2.98e-8 on
+# conv_relu_softmax under XLA's CPU backend), not bit for bit. The
+# interpreted == linked == fused identity stays exact: those paths run
+# one executable shape.
+BATCHED_RTOL = 1e-5
+BATCHED_ATOL = 1e-6
+
+
+def assert_batched_close(ref, got, err_msg: str = "") -> None:
+    """A batched or coalesced output against its serial reference."""
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=BATCHED_RTOL, atol=BATCHED_ATOL,
+                               err_msg=err_msg)
 
 
 def _np(v):
@@ -116,12 +134,12 @@ def _reference(prog, files, inputs):
     return fs, ex, {k: _np(v) for k, v in ref.items()}
 
 
-def _assert_same(ref: dict, got: dict, label: str):
+def _assert_same(ref: dict, got: dict, label: str, batched: bool = False):
     assert set(got) == set(ref), \
         f"{label}: outputs {sorted(got)} != {sorted(ref)}"
+    check = assert_batched_close if batched else np.testing.assert_array_equal
     for k in ref:
-        np.testing.assert_array_equal(
-            ref[k], _np(got[k]), err_msg=f"{label}: output {k!r} diverged")
+        check(ref[k], _np(got[k]), err_msg=f"{label}: output {k!r} diverged")
 
 
 _CASES = None
@@ -263,8 +281,8 @@ def _rand_inputs_like(inputs: dict, rng) -> dict:
 @pytest.mark.parametrize("name", ["conv_relu_softmax", "gemm_chain"])
 def test_batched_matches_serial(name, n):
     """run_batched over N random inputs == N serial run / run_interpreted
-    calls, bit for bit — including N that are not bucket sizes (the
-    pad-to-bucket + slice-back path)."""
+    calls within the batched tolerance — including N that are not bucket
+    sizes (the pad-to-bucket + slice-back path)."""
     from repro.core import linker
     name, prog, files, inputs = next(c for c in _cases() if c[0] == name)
     rng = np.random.RandomState(100 + n)
@@ -280,10 +298,10 @@ def test_batched_matches_serial(name, n):
         ref = ex.run_interpreted(rbl.bind(prog, rimfs=fs,
                                           inputs=dict(req)))
         _assert_same({k: _np(v) for k, v in ref.items()}, got,
-                     f"{name}/batched@{n} vs interpreted")
+                     f"{name}/batched@{n} vs interpreted", batched=True)
         ref2 = ex.run(bound, inputs=dict(req), rimfs=fs)
         _assert_same({k: _np(v) for k, v in ref2.items()}, got,
-                     f"{name}/batched@{n} vs linked")
+                     f"{name}/batched@{n} vs linked", batched=True)
 
 
 @pytest.mark.parametrize("n", (1, 5, 8))
@@ -305,7 +323,7 @@ def test_batched_resnet18_matches_serial(n):
     assert ex.batch_stats["batchable"]
     for req, got in zip(batch, outs):
         ref = {k: _np(v) for k, v in ex.run(bound, inputs=req).items()}
-        _assert_same(ref, got, f"resnet/batched@{n}")
+        _assert_same(ref, got, f"resnet/batched@{n}", batched=True)
 
 
 @pytest.mark.parametrize("name", ["matmul_dma", "dma_pipeline",
@@ -495,64 +513,59 @@ def test_stream_propagates_tile_failure():
 
 
 # ---------------------------------------------------------------------------
-# Hypothesis-generated programs (optional dependency, like the other suites)
+# Hypothesis-generated programs
 # ---------------------------------------------------------------------------
 
-try:
-    from hypothesis import given, settings, strategies as st
-    _HAS_HYPOTHESIS = True
-except ImportError:                       # optional test dependency
-    _HAS_HYPOTHESIS = False
 
-if _HAS_HYPOTHESIS:
-    def _random_program(draw_ops):
-        """Random straight-line program over (4, 6) f32 buffers (the
-        test_executor_property generator, reused for the partition
-        matrix): every intermediate symbol is a potential cut edge."""
-        tensors = {
-            "in0": TensorDesc("in0", (4, 6), "float32", "input"),
-            "w0": TensorDesc("w0", (6, 6), "float32", "weight"),
-        }
-        syms = ["in0"]
-        ops = []
-        for i, choice in enumerate(draw_ops):
-            src = syms[choice % len(syms)]
-            dst = f"t{i}"
-            kind = choice % 4
-            tensors[dst] = TensorDesc(dst, (4, 6), "float32", "scratch")
-            if kind == 0:
-                ops.append(RCBOp(Op.RELU, (dst,), (src,)))
-            elif kind == 1:
-                ops.append(RCBOp(Op.SOFTMAX, (dst,), (src,), {"axis": -1}))
-            elif kind == 2:
-                other = syms[(choice // 4) % len(syms)]
-                ops.append(RCBOp(Op.ADD, (dst,), (src, other)))
-            else:
-                ops.append(RCBOp(Op.GEMM, (dst,), (src, "w0")))
-            syms.append(dst)
-        out = syms[-1]
-        tensors[out] = TensorDesc(out, (4, 6), "float32", "output")
-        prog = RCBProgram("rand", tensors,
-                          [RCB(0, "layer", (), tuple(ops))])
-        prog.validate()
-        return prog
+def _random_program(draw_ops):
+    """Random straight-line program over (4, 6) f32 buffers (the
+    test_executor_property generator, reused for the partition
+    matrix): every intermediate symbol is a potential cut edge."""
+    tensors = {
+        "in0": TensorDesc("in0", (4, 6), "float32", "input"),
+        "w0": TensorDesc("w0", (6, 6), "float32", "weight"),
+    }
+    syms = ["in0"]
+    ops = []
+    for i, choice in enumerate(draw_ops):
+        src = syms[choice % len(syms)]
+        dst = f"t{i}"
+        kind = choice % 4
+        tensors[dst] = TensorDesc(dst, (4, 6), "float32", "scratch")
+        if kind == 0:
+            ops.append(RCBOp(Op.RELU, (dst,), (src,)))
+        elif kind == 1:
+            ops.append(RCBOp(Op.SOFTMAX, (dst,), (src,), {"axis": -1}))
+        elif kind == 2:
+            other = syms[(choice // 4) % len(syms)]
+            ops.append(RCBOp(Op.ADD, (dst,), (src, other)))
+        else:
+            ops.append(RCBOp(Op.GEMM, (dst,), (src, "w0")))
+        syms.append(dst)
+    out = syms[-1]
+    tensors[out] = TensorDesc(out, (4, 6), "float32", "output")
+    prog = RCBProgram("rand", tensors,
+                      [RCB(0, "layer", (), tuple(ops))])
+    prog.validate()
+    return prog
 
-    @given(st.lists(st.integers(0, 1000), min_size=2, max_size=16),
-           st.sampled_from(TILE_COUNTS))
-    @settings(max_examples=25, deadline=None)
-    def test_property_partitioned_matches_linked(draw_ops, n_groups):
-        prog = _random_program(draw_ops)
-        rng = np.random.RandomState(0)
-        fs = rimfs.mount(rimfs.pack(
-            {"w0": rng.randn(6, 6).astype(np.float32)}))
-        x = rng.randn(4, 6).astype(np.float32)
-        ex = Executor()
-        ref = {k: _np(v) for k, v in ex.run(
-            rbl.bind(prog, rimfs=fs, inputs={"in0": x})).items()}
-        bound = rbl.bind(prog, rimfs=fs, inputs={"in0": x})
-        got = ex.run_partitioned(bound, rimfs=fs,
-                                 mesh=rhal.TileMesh(n_groups))
-        _assert_same(ref, got, f"rand/partitioned@{n_groups}")
+
+@given(st.lists(st.integers(0, 1000), min_size=2, max_size=16),
+       st.sampled_from(TILE_COUNTS))
+@settings(max_examples=25, deadline=None)
+def test_property_partitioned_matches_linked(draw_ops, n_groups):
+    prog = _random_program(draw_ops)
+    rng = np.random.RandomState(0)
+    fs = rimfs.mount(rimfs.pack(
+        {"w0": rng.randn(6, 6).astype(np.float32)}))
+    x = rng.randn(4, 6).astype(np.float32)
+    ex = Executor()
+    ref = {k: _np(v) for k, v in ex.run(
+        rbl.bind(prog, rimfs=fs, inputs={"in0": x})).items()}
+    bound = rbl.bind(prog, rimfs=fs, inputs={"in0": x})
+    got = ex.run_partitioned(bound, rimfs=fs,
+                             mesh=rhal.TileMesh(n_groups))
+    _assert_same(ref, got, f"rand/partitioned@{n_groups}")
 
 
 # ---------------------------------------------------------------------------

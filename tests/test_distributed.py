@@ -26,14 +26,13 @@ def test_compressed_psum_matches_exact():
     _run("""
     import jax, jax.numpy as jnp, numpy as np, functools
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.distributed.collectives import compressed_psum, \
-        shard_map_compat
+    from repro.distributed.collectives import compressed_psum
     mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
     g = jnp.asarray(np.random.RandomState(0).randn(8, 64), jnp.float32)
     exact = jnp.mean(g, axis=0)
     for method, tol in [("none", 1e-6), ("bf16", 2e-2), ("int8_ef", 3e-2)]:
-        @functools.partial(shard_map_compat, mesh=mesh, in_specs=P("data"),
-                           out_specs=P("data"))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
+                           out_specs=P("data"), check_vma=False)
         def red(x, method=method):
             r, _ = compressed_psum(x[0], "data", method)
             return r[None]
@@ -50,14 +49,13 @@ def test_int8_error_feedback_converges():
     _run("""
     import jax, jax.numpy as jnp, numpy as np, functools
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.distributed.collectives import compressed_psum, \
-        shard_map_compat
+    from repro.distributed.collectives import compressed_psum
     mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
     g = jnp.asarray(np.random.RandomState(1).randn(8, 32), jnp.float32)
     exact = jnp.mean(g, axis=0)
-    @functools.partial(shard_map_compat, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data")))
+                       out_specs=(P("data"), P("data")), check_vma=False)
     def red(x, e):
         r, ne = compressed_psum(x[0], "data", "int8_ef", e[0])
         return r[None], ne[None]

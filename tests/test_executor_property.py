@@ -5,7 +5,6 @@ op vocabulary, not just hand-picked ones)."""
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis")  # optional test dependency
 from hypothesis import given, settings, strategies as st
 
 from repro.core import rbl, rimfs

@@ -39,3 +39,26 @@ def test_model_forward_flash_vs_jnp():
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=env, timeout=560)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
+
+
+def test_registry_stats_count_the_implementation_that_ran():
+    """``stats`` counts a call under the implementation that produced it:
+    non-causal attention on ragged tiles is refused by the spec and runs
+    the ref oracle (counted ``attention_ref``); causal attention on the
+    same shapes runs the Pallas kernel (``attention_pallas``)."""
+    import numpy as np
+    import jax.numpy as jnp
+    from repro.kernels.registry import KernelRegistry, _build_default_registry
+
+    reg = _build_default_registry()
+    assert isinstance(reg, KernelRegistry)
+    rng = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(rng.randn(1, 40, 2, 16), jnp.float32)
+               for _ in range(3))
+    params = {"block_q": 16, "block_k": 16}        # 40 % 16: ragged
+    out = reg.call("attention", q, k, v, causal=False, params=params)
+    ref = reg.call("attention", q, k, v, causal=False, impl="ref")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    assert reg.stats == {"attention_ref": 2}
+    reg.call("attention", q, k, v, causal=True, params=params)
+    assert reg.stats == {"attention_ref": 2, "attention_pallas": 1}

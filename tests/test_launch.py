@@ -84,3 +84,25 @@ def test_applicable_shapes_policy():
     for a in ("qwen3-14b", "rwkv6-1.6b"):
         assert {"train_4k", "prefill_32k", "decode_32k"} <= \
             set(applicable_shapes(get_config(a)))
+
+
+def test_compile_cache_placed_from_outside_or_fixed(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, decides where the persistent
+    cache lives; otherwise it is <repo>/.jax_cache, the same path in every
+    run (the path is part of each entry's key)."""
+    import jax
+    from pathlib import Path
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    root = Path(compile_cache.__file__).resolve().parents[3]
+    assert (root / "pyproject.toml").is_file()
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was  # left alone
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root /
+                                                           ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -1,11 +1,7 @@
 """Paged KV cache: allocation/lifetime invariants + attention equivalence."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-    _HAS_HYPOTHESIS = True
-except ImportError:                       # optional test dependency
-    _HAS_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 
@@ -176,24 +172,23 @@ def test_engine_exhaustion_lifecycle_chaos(rng):
     assert served > 0        # freed blocks were reused across rounds
 
 
-if _HAS_HYPOTHESIS:
-    @given(st.lists(st.tuples(st.integers(0, 5), st.integers(1, 9)),
-                    min_size=1, max_size=24))
-    @settings(max_examples=40, deadline=None)
-    def test_property_no_block_leaks_or_double_use(ops):
-        """Interleaved allocate/grow/release never leaks or double-books a
-        physical block."""
-        c = _cache(blocks=12, bs=2)
-        for seq, tokens in ops:
-            try:
-                if seq in c.tables:
-                    c.release(seq)
-                else:
-                    c.allocate(seq, tokens=tokens)
-            except OutOfBlocksError:
-                pass
-            # invariants
-            held = [b for t in c.tables.values() for b in t]
-            assert len(held) == len(set(held))              # no double-booking
-            assert len(held) + c.free_blocks() == 12        # no leaks
-            assert set(held).isdisjoint(c._free)
+@given(st.lists(st.tuples(st.integers(0, 5), st.integers(1, 9)),
+                min_size=1, max_size=24))
+@settings(max_examples=40, deadline=None)
+def test_property_no_block_leaks_or_double_use(ops):
+    """Interleaved allocate/grow/release never leaks or double-books a
+    physical block."""
+    c = _cache(blocks=12, bs=2)
+    for seq, tokens in ops:
+        try:
+            if seq in c.tables:
+                c.release(seq)
+            else:
+                c.allocate(seq, tokens=tokens)
+        except OutOfBlocksError:
+            pass
+        # invariants
+        held = [b for t in c.tables.values() for b in t]
+        assert len(held) == len(set(held))              # no double-booking
+        assert len(held) + c.free_blocks() == 12        # no leaks
+        assert set(held).isdisjoint(c._free)

@@ -2,7 +2,6 @@
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis")  # optional test dependency
 from hypothesis import given, settings, strategies as st
 
 from repro.core.quant import per_channel_scales, quantize_weight

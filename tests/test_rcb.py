@@ -2,11 +2,7 @@
 import json
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-    _HAS_HYPOTHESIS = True
-except ImportError:                       # optional test dependency
-    _HAS_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.core.rcb import Op, RCB, RCBOp, RCBProgram, TensorDesc
 
@@ -141,22 +137,21 @@ def test_v2_unknown_version_rejected():
         RCBProgram.decode(bytes(blob))
 
 
-if _HAS_HYPOTHESIS:
-    _sym = st.text(alphabet="abcdefgh_0123456789", min_size=1, max_size=8)
-    _attr_val = st.one_of(st.integers(-1000, 1000), st.booleans(),
-                          st.floats(-1e3, 1e3, allow_nan=False),
-                          st.lists(st.integers(0, 64), max_size=4))
+_sym = st.text(alphabet="abcdefgh_0123456789", min_size=1, max_size=8)
+_attr_val = st.one_of(st.integers(-1000, 1000), st.booleans(),
+                      st.floats(-1e3, 1e3, allow_nan=False),
+                      st.lists(st.integers(0, 64), max_size=4))
 
 
-    @given(st.lists(
-        st.builds(RCBOp,
-                  st.sampled_from(list(Op)),
-                  st.lists(_sym, max_size=3).map(tuple),
-                  st.lists(_sym, max_size=3).map(tuple),
-                  st.dictionaries(_sym, _attr_val, max_size=4)),
-        max_size=16))
-    @settings(max_examples=50, deadline=None)
-    def test_property_block_roundtrip(ops):
-        blk = RCB(3, "pipeline", (0, 1), tuple(ops))
-        back, _ = RCB.decode(memoryview(blk.encode()))
-        assert back == blk
+@given(st.lists(
+    st.builds(RCBOp,
+              st.sampled_from(list(Op)),
+              st.lists(_sym, max_size=3).map(tuple),
+              st.lists(_sym, max_size=3).map(tuple),
+              st.dictionaries(_sym, _attr_val, max_size=4)),
+    max_size=16))
+@settings(max_examples=50, deadline=None)
+def test_property_block_roundtrip(ops):
+    blk = RCB(3, "pipeline", (0, 1), tuple(ops))
+    back, _ = RCB.decode(memoryview(blk.encode()))
+    assert back == blk

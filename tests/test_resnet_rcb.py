@@ -1,4 +1,6 @@
 """ResNet-18 case study through the full AEG path (paper §3.3/§4.3)."""
+import dataclasses
+
 import numpy as np
 
 import jax
@@ -7,7 +9,7 @@ import jax.numpy as jnp
 from repro.configs.resnet18 import CONFIG
 from repro.core import quant, rbl, rctc, rimfs
 from repro.core.executor import Executor
-from repro.core.rcb import RCBProgram
+from repro.core.rcb import Op, RCBProgram
 from repro.models import resnet as rn
 
 
@@ -71,3 +73,30 @@ def test_weights_image_size_tracks_params(rng):
     _, image = rctc.compile_resnet18(cfg, folded, batch=1)
     payload = sum(np.asarray(v).nbytes for v in folded.values())
     assert len(image) < payload * 1.02 + 4096
+
+
+def test_published_widths_64px_runs_stem_maxpool(rng):
+    """ResNet-18 at its published widths on a 64x64 input, batch 1: the
+    smallest image for which the lowering emits the stem max-pool, so the
+    RCB path runs the op sequence the served 224x224 model runs. The
+    interpreted, linked and batched paths each match the oracle; the
+    interpreted and linked paths match each other bit for bit."""
+    from test_conformance import assert_batched_close
+    cfg = dataclasses.replace(CONFIG, image_size=64)
+    params = rn.init_resnet(jax.random.PRNGKey(0), cfg)
+    prog, image = rctc.compile_resnet18(cfg, rn.fold_bn(params), batch=1)
+    assert Op.MAXPOOL in {op.op for blk in prog.blocks for op in blk.ops}
+    fs = rimfs.mount(image)
+    xs = [rng.rand(1, 64, 64, 3).astype(np.float32) for _ in range(3)]
+    ex = Executor()
+    bound = rbl.bind(prog, rimfs=fs)
+    batched = ex.run_batched(bound, [{"input": x} for x in xs], rimfs=fs)
+    assert ex.batch_stats["batchable"]
+    for x, got in zip(xs, batched):
+        ref = np.asarray(rn.resnet_forward(cfg, params, jnp.asarray(x)))
+        b = rbl.bind(prog, rimfs=fs, inputs={"input": x})
+        interp = np.asarray(ex.run_interpreted(b)["output"])
+        linked = np.asarray(ex.run(b)["output"])
+        np.testing.assert_array_equal(interp, linked)
+        np.testing.assert_allclose(linked, ref, atol=1e-5, rtol=1e-5)
+        assert_batched_close(linked, got["output"])

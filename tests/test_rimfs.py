@@ -2,11 +2,7 @@
 device residency (pin-once, zero re-upload)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-    _HAS_HYPOTHESIS = True
-except ImportError:                       # optional test dependency
-    _HAS_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.core import rhal, rimfs
 
@@ -143,18 +139,17 @@ def test_overhead_small(rng):
     assert fs.overhead_bytes() < 0.01 * fs.total_bytes()
 
 
-if _HAS_HYPOTHESIS:
-    @given(st.dictionaries(
-        st.text("abcdef", min_size=1, max_size=6),
-        st.tuples(st.sampled_from(["float32", "int8", "int32", "float16"]),
-                  st.lists(st.integers(1, 5), min_size=0, max_size=3)),
-        min_size=1, max_size=8))
-    @settings(max_examples=30, deadline=None)
-    def test_property_roundtrip(spec):
-        rng = np.random.RandomState(42)
-        files = {k: (np.asarray(rng.randn(*shape)) * 10).astype(dt)
-                 for k, (dt, shape) in spec.items()}
-        fs = rimfs.mount(rimfs.pack(files))
-        assert fs.verify()
-        for k, v in files.items():
-            np.testing.assert_array_equal(fs.read(k), v)
+@given(st.dictionaries(
+    st.text("abcdef", min_size=1, max_size=6),
+    st.tuples(st.sampled_from(["float32", "int8", "int32", "float16"]),
+              st.lists(st.integers(1, 5), min_size=0, max_size=3)),
+    min_size=1, max_size=8))
+@settings(max_examples=30, deadline=None)
+def test_property_roundtrip(spec):
+    rng = np.random.RandomState(42)
+    files = {k: (np.asarray(rng.randn(*shape)) * 10).astype(dt)
+             for k, (dt, shape) in spec.items()}
+    fs = rimfs.mount(rimfs.pack(files))
+    assert fs.verify()
+    for k, v in files.items():
+        np.testing.assert_array_equal(fs.read(k), v)

@@ -1,26 +1,15 @@
 """RBL sharding resolution: the shape-aware logical->physical rule engine."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-    _HAS_HYPOTHESIS = True
-except ImportError:                       # optional test dependency
-    _HAS_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 import jax
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.distributed.sharding import RULE_SETS, logical_to_pspec
 
-def _abstract_mesh(sizes, names):
-    try:                       # jax >= 0.5: AbstractMesh(sizes, names)
-        return AbstractMesh(sizes, names)
-    except TypeError:          # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH1 = _abstract_mesh((16, 16), ("data", "model"))
-MESH2 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_train_batch_uses_pod_and_data():
@@ -83,27 +72,26 @@ _LOGICAL = ["batch", "seq", "embed", "heads", "kv_heads", "mlp", "experts",
             "vocab", "fsdp", "state", "layers", None]
 
 
-if _HAS_HYPOTHESIS:
-    @given(st.lists(st.tuples(st.sampled_from(_LOGICAL),
-                              st.integers(1, 4096)), min_size=1, max_size=5),
-           st.sampled_from(["train", "prefill", "decode"]))
-    @settings(max_examples=200, deadline=None)
-    def test_property_resolver_invariants(dims, mode):
-        """For ANY shape/axes combination: every mesh axis is used at most once
-        and every sharded dim is divisible by its mesh-axes product."""
-        axes = tuple(a for a, _ in dims)
-        shape = tuple(s for _, s in dims)
-        spec = logical_to_pspec(shape, axes, RULE_SETS[mode], MESH2)
-        sizes = {"pod": 2, "data": 16, "model": 16}
-        used = []
-        for dim, entry in zip(shape, tuple(spec) + (None,) * len(shape)):
-            if entry is None:
-                continue
-            group = (entry,) if isinstance(entry, str) else tuple(entry)
-            used.extend(group)
-            total = int(np.prod([sizes[a] for a in group]))
-            assert dim % total == 0
-        assert len(used) == len(set(used))
+@given(st.lists(st.tuples(st.sampled_from(_LOGICAL),
+                          st.integers(1, 4096)), min_size=1, max_size=5),
+       st.sampled_from(["train", "prefill", "decode"]))
+@settings(max_examples=200, deadline=None)
+def test_property_resolver_invariants(dims, mode):
+    """For ANY shape/axes combination: every mesh axis is used at most once
+    and every sharded dim is divisible by its mesh-axes product."""
+    axes = tuple(a for a, _ in dims)
+    shape = tuple(s for _, s in dims)
+    spec = logical_to_pspec(shape, axes, RULE_SETS[mode], MESH2)
+    sizes = {"pod": 2, "data": 16, "model": 16}
+    used = []
+    for dim, entry in zip(shape, tuple(spec) + (None,) * len(shape)):
+        if entry is None:
+            continue
+        group = (entry,) if isinstance(entry, str) else tuple(entry)
+        used.extend(group)
+        total = int(np.prod([sizes[a] for a in group]))
+        assert dim % total == 0
+    assert len(used) == len(set(used))
 
 
 def test_shard_noop_outside_context():
