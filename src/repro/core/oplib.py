@@ -79,7 +79,22 @@ def maxpool(x, attrs):
 
 
 def avgpool_global(x, attrs):
-    return jnp.mean(x, axis=(1, 2))
+    """Mean over H and W, summed as a fixed tree of elementwise adds.
+
+    A reduce is summed in whatever order XLA picks, and that order changes
+    when a producer (a RELU) fuses into it: ``jnp.mean`` on the staged
+    program then differs from the op-by-op run in the last ulp. Adds
+    cannot be reordered, so every path (interpreted, linked, fused,
+    vmapped) sums alike. Zero padding to a power of two is exact.
+    """
+    n, h, w, c = x.shape
+    s = x.reshape(n, h * w, c)
+    width = 1 << (h * w - 1).bit_length()
+    s = jnp.pad(s, ((0, 0), (0, width - h * w), (0, 0)))
+    while s.shape[1] > 1:
+        half = s.shape[1] // 2
+        s = s[:, :half] + s[:, half:]
+    return s[:, 0] * (1.0 / (h * w))
 
 
 def scale_shift(x, scale, shift, attrs=None):

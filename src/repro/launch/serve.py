@@ -119,18 +119,18 @@ def drive_resnet(addr: tuple, cfg: ResNetConfig, batch: int, requests: int,
 
 
 def serve_resnet(cfg: ResNetConfig, requests: int, batch: int, clients: int,
-                 pipeline: int, batch_window: int = 8, seed: int = 0) -> None:
+                 pipeline: int, batch_window: int = 8) -> None:
     t0 = time.perf_counter()
-    _, server, c0 = start_resnet(cfg, seed, batch, batch_window)
+    _, server, c0 = start_resnet(cfg, 0, batch, batch_window)
     print(f"[serve] {cfg.name} provisioned on {server.address}")
     try:
-        drive_resnet(server.address, cfg, batch, 1, 1, 1, seed)
+        drive_resnet(server.address, cfg, batch, 1, 1, 1)
         buckets = server.warm()
         print(f"[serve] set-up {time.perf_counter() - t0:.1f}s "
               f"(batch buckets {buckets} compiled)")
         t0 = time.perf_counter()
         n = len(drive_resnet(server.address, cfg, batch, requests, clients,
-                             pipeline, seed + 1))
+                             pipeline, 1))
         dt = time.perf_counter() - t0
         tel = c0.telemetry()
         srv = tel.get("serving", {})
@@ -247,13 +247,13 @@ def lm_prompts(cfg, n: int, length: int, seed: int = 0) -> list:
             for _ in range(n)]
 
 
-def serve_lm(cfg, requests: int, prompt_len: int = 16, max_new: int = 8,
-             seed: int = 0) -> None:
+def serve_lm(cfg, requests: int, prompt_len: int = 16,
+             max_new: int = 8) -> None:
     t0 = time.perf_counter()
-    _, engine, server = start_lm(cfg, seed)
+    _, engine, server = start_lm(cfg)
     print(f"[serve-lm] {cfg.name} set-up {time.perf_counter() - t0:.1f}s")
     try:
-        prompts = lm_prompts(cfg, requests, prompt_len, seed)
+        prompts = lm_prompts(cfg, requests, prompt_len)
         t0 = time.perf_counter()
         outs = drive_lm(server.address, prompts, max_new)
         dt = time.perf_counter() - t0
@@ -272,7 +272,6 @@ def main() -> None:
     ap.add_argument("--model", default="resnet18-smoke",
                     help="resnet18[-smoke] or an LM config, e.g. "
                          "qwen2-1.5b[-smoke]")
-    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--batch", type=int, default=4,
                     help="resnet: images per request")
@@ -299,10 +298,9 @@ def main() -> None:
     cfg = model_config(args.model)
     if isinstance(cfg, ResNetConfig):
         serve_resnet(cfg, args.requests, args.batch, args.clients,
-                     args.pipeline, batch_window=args.batch_window,
-                     seed=args.seed)
+                     args.pipeline, batch_window=args.batch_window)
     else:
-        serve_lm(cfg, args.requests, seed=args.seed)
+        serve_lm(cfg, args.requests)
 
 
 if __name__ == "__main__":

@@ -746,12 +746,13 @@ class InferenceServer:
             raise box["error"]
         return box["result"]
 
-    def warm(self, timeout: float = 1200.0) -> list:
+    def warm(self) -> list:
         """Compile, on the dispatcher, every batch bucket the coalescing
         window can form for the provisioned program, so no served
         dispatch compiles. A window coalesces runs of 2 or more (a solo
         request takes the linked path), so bucket 1 is left out. Returns
-        the buckets compiled."""
+        the buckets compiled; waits up to 1200 s, several full-width
+        bucket compiles."""
         def prepare() -> list:
             if self._bound is None:
                 raise RuntimeError("not provisioned")
@@ -760,7 +761,7 @@ class InferenceServer:
             return sorted({b for n in range(2, self.batch_window + 1)
                            for b in self.executor.prepare_batched(
                                self._bound, n)})
-        return self.run_on_dispatcher(prepare, timeout=timeout)
+        return self.run_on_dispatcher(prepare, timeout=1200.0)
 
     def _pump_engine(self) -> bool:
         """ServiceLoop idle hook: one continuous-batching decode step,

@@ -17,6 +17,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import rbl, rctc, rhal, rimfs
 from repro.core.executor import Executor
@@ -178,6 +179,21 @@ def test_conformance_partitioned(name, n_groups):
     part = bound._partitions[mesh.n_groups]
     # the mesh's movement accounting covers exactly the cut-edge table
     assert mesh.moved_bytes() == part.cut_bytes()
+
+
+@pytest.mark.parametrize("hw", [(1, 1), (3, 5), (7, 7), (8, 8)])
+def test_avgpool_global_sums_alike_fused_and_alone(hw):
+    """A RELU fused into global average pooling by one staged executable
+    gives the same bits as the two ops run one by one, and the mean agrees
+    with ``jnp.mean``."""
+    from repro.core import oplib
+    x = np.random.RandomState(sum(hw)).randn(2, *hw, 9).astype(np.float32)
+    alone = _np(oplib.avgpool_global(oplib.relu(jnp.asarray(x), {}), {}))
+    fused = _np(jax.jit(lambda a: oplib.avgpool_global(
+        oplib.relu(a, {}), {}))(x))
+    np.testing.assert_array_equal(fused, alone)
+    np.testing.assert_allclose(alone, np.maximum(x, 0).mean(axis=(1, 2)),
+                               rtol=1e-6, atol=1e-7)
 
 
 @pytest.mark.parametrize("n_groups", TILE_COUNTS)

@@ -51,14 +51,13 @@ def _start(prog, image, **kw):
 # ---------------------------------------------------------------- pipelining
 def test_pipelined_multiclient_bit_identical(resnet_setup):
     """4 concurrent connections x 3 pipelined requests each == the same 12
-    requests run serially: bit for bit when served solo, within the
-    batched tolerance (test_conformance.BATCHED_RTOL) when the window
-    coalesced them."""
+    requests run serially, bit for bit. ``batch_window=1`` serves every
+    request solo: this tests framing and routing, not coalescing."""
     cfg, prog, image = resnet_setup
     n_clients, per_client = 4, 3
     inputs = {(c, i): _input(cfg, 100 * c + i)
               for c in range(n_clients) for i in range(per_client)}
-    server, addr, client = _start(prog, image)
+    server, addr, client = _start(prog, image, batch_window=1)
     try:
         serial = {k: client.infer(input=v)["output"]
                   for k, v in sorted(inputs.items())}
@@ -87,7 +86,7 @@ def test_pipelined_multiclient_bit_identical(resnet_setup):
         assert not errors, errors
         assert set(results) == set(inputs)          # zero dropped frames
         for k in inputs:
-            assert_batched_close(serial[k], results[k])
+            np.testing.assert_array_equal(results[k], serial[k])
     finally:
         client.close()
         server.stop()
@@ -96,9 +95,9 @@ def test_pipelined_multiclient_bit_identical(resnet_setup):
 def test_interleaved_request_ids_one_connection(resnet_setup):
     """One connection pipelines 6 requests and collects the responses in a
     scrambled order — request ids route every response to its waiter
-    (the backlog may coalesce: batched tolerance)."""
+    (``batch_window=1``: served solo, so bit for bit)."""
     cfg, prog, image = resnet_setup
-    server, addr, client = _start(prog, image)
+    server, addr, client = _start(prog, image, batch_window=1)
     try:
         xs = [_input(cfg, 50 + i) for i in range(6)]
         refs = [client.infer(input=x)["output"] for x in xs]
@@ -108,7 +107,7 @@ def test_interleaved_request_ids_one_connection(resnet_setup):
         for j in order:
             got[j] = client.result(rids[j])["output"]
         for j in range(6):
-            assert_batched_close(refs[j], got[j])
+            np.testing.assert_array_equal(got[j], refs[j])
     finally:
         client.close()
         server.stop()
@@ -116,11 +115,10 @@ def test_interleaved_request_ids_one_connection(resnet_setup):
 
 def test_midstream_provision_does_not_corrupt_inflight(resnet_setup):
     """A PROVISION racing pipelined INFERs serializes behind the
-    dispatcher: in-flight inferences stay uncorrupted (within the batched
-    tolerance, as they may coalesce) and requests after the re-provision
-    still serve."""
+    dispatcher: in-flight inferences stay bit-identical (``batch_window=1``
+    serves them solo) and requests after the re-provision still serve."""
     cfg, prog, image = resnet_setup
-    server, addr, client = _start(prog, image)
+    server, addr, client = _start(prog, image, batch_window=1)
     other = Client(addr)
     try:
         xs = [_input(cfg, 200 + i) for i in range(4)]
@@ -130,7 +128,7 @@ def test_midstream_provision_does_not_corrupt_inflight(resnet_setup):
         rids += [client.infer_async(input=x) for x in xs[2:]]
         assert status["status"] == "ready"
         for rid, ref in zip(rids, refs):
-            assert_batched_close(ref, client.result(rid)["output"])
+            np.testing.assert_array_equal(client.result(rid)["output"], ref)
     finally:
         other.close()
         client.close()
