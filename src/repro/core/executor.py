@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.core import linker as linker_mod
 from repro.core import rhal as rhal_mod
+from repro.core import tracing
 from repro.core.rbl import BoundProgram
 from repro.core.rbl import explicitly_freed as rbl_explicitly_freed
 from repro.core.rcb import Op, RCBProgram
@@ -162,6 +163,10 @@ class Executor:
 
         ``trace_ops=True`` falls back to the interpreted path: per-op wall
         timing needs the per-op host sync that defines that mode.
+
+        The thunks are issued with no host sync of their own (the
+        ``aeg.issue`` span, ``core/tracing.py``); the program's FENCE ops
+        and the caller's readback wait for the device.
         """
         if trace_ops:
             return self.run_interpreted(bound, inputs=inputs, rimfs=rimfs,
@@ -181,39 +186,21 @@ class Executor:
             for i, buf in enumerate(slots):
                 if buf is not None:
                     _probe_update(probe_dev, linked.names[i], buf)
-        for pre in linked.prologue:                # prefetch issue phase
-            pre(slots, rimfs)
-        if probe_dev is None and self.rtpm is None:
-            for thunk in linked.thunks:            # THE hot loop
-                thunk(slots, rimfs)
-        else:                                      # instrumented (composable)
-            thunks = linked.thunks
-            metas = linked.metas
-            for block_id, start, end in linked.block_spans:
-                t_blk = time.perf_counter()
-                for k in range(start, end):
-                    thunks[k](slots, rimfs)
-                    if probe_dev is not None:
-                        for d in metas[k].dst_slots:
-                            buf = slots[d]
-                            if buf is not None and \
-                                    type(buf) is not DmaTicket:
-                                _probe_update(probe_dev, linked.names[d],
-                                              buf)
-                if self.rtpm is not None:
-                    # sync the block's products so "seconds" reflects
-                    # execution, not async enqueue
-                    for k in range(start, end):
-                        for d in metas[k].dst_slots:
-                            buf = slots[d]
-                            if buf is not None and hasattr(
-                                    buf, "block_until_ready"):
-                                buf.block_until_ready()
-                    self.rtpm.post("rcb_complete",
-                                   {"block": block_id,
-                                    "seconds": time.perf_counter() - t_blk})
-        for epi in linked.epilogue:                # drain redeem phase
-            epi(slots, rimfs)
+        with tracing.span("aeg.issue", thunks=len(linked.thunks)):
+            for pre in linked.prologue:            # prefetch issue phase
+                pre(slots, rimfs)
+            if probe_dev is None:
+                for thunk in linked.thunks:        # THE hot loop
+                    thunk(slots, rimfs)
+            else:
+                for thunk, meta in zip(linked.thunks, linked.metas):
+                    thunk(slots, rimfs)
+                    for d in meta.dst_slots:
+                        buf = slots[d]
+                        if buf is not None and type(buf) is not DmaTicket:
+                            _probe_update(probe_dev, linked.names[d], buf)
+            for epi in linked.epilogue:            # drain redeem phase
+                epi(slots, rimfs)
         self.driver._count("dispatch", linked.n_compute)
         plan = linked.residency
         if self.rtpm is not None and plan is not None and plan.bytes_moved:
@@ -261,7 +248,6 @@ class Executor:
                 _probe_update(probe_dev, sym, buf)
         idx = 0
         for block in bound.program.blocks:
-            t_blk = time.perf_counter()
             for op in block.ops:
                 t0 = time.perf_counter()
                 self._dispatch(self.driver, op, buffers, bound.last_use,
@@ -275,10 +261,6 @@ class Executor:
                         if dd in buffers:
                             _probe_update(probe_dev, dd, buffers[dd])
                 idx += 1
-            if self.rtpm is not None:
-                self.rtpm.post("rcb_complete",
-                               {"block": block.block_id,
-                                "seconds": time.perf_counter() - t_blk})
         if probe_dev is not None:
             _probe_flush(probe, probe_dev)
         return {name: buffers[name]
@@ -459,36 +441,39 @@ class Executor:
         # phase 1: stack + dispatch every chunk (no sync anywhere)
         pending: list = []                 # (pos, take, {sym: device out})
         pos = 0
-        for take, bucket in self._chunks(len(reqs), max_bucket):
-            chunk = reqs[pos:pos + take]
-            stacked = {}
-            for sym in input_syms:
-                vals = []
-                for req in chunk:
-                    v = req.get(sym) if req else None
-                    if v is None:
-                        v = bound.buffers.get(sym)
-                    if v is None:
-                        raise ValueError(f"missing input {sym!r} in "
-                                         f"batched request {pos}")
-                    vals.append(np.asarray(v))
-                vals.extend([vals[-1]] * (bucket - take))   # pad lanes
-                stacked[sym] = np.stack(vals)      # host-side: one memcpy
-            fn = self._batched_callable(bound, bucket)
-            pending.append((pos, take, fn(stacked, weights)))
-            self.batch_stats["buckets"].append(bucket)
-            self.batch_stats["padded"] += bucket - take
-            pos += take
+        with tracing.span("aeg.issue") as issue:
+            for take, bucket in self._chunks(len(reqs), max_bucket):
+                chunk = reqs[pos:pos + take]
+                stacked = {}
+                for sym in input_syms:
+                    vals = []
+                    for req in chunk:
+                        v = req.get(sym) if req else None
+                        if v is None:
+                            v = bound.buffers.get(sym)
+                        if v is None:
+                            raise ValueError(f"missing input {sym!r} in "
+                                             f"batched request {pos}")
+                        vals.append(np.asarray(v))
+                    vals.extend([vals[-1]] * (bucket - take))   # pad lanes
+                    stacked[sym] = np.stack(vals)      # host-side: one memcpy
+                fn = self._batched_callable(bound, bucket)
+                pending.append((pos, take, fn(stacked, weights)))
+                self.batch_stats["buckets"].append(bucket)
+                self.batch_stats["padded"] += bucket - take
+                pos += take
+            issue.stats["thunks"] = len(pending)
         # phase 2: materialize in order — ONE d2h per output tensor per
         # chunk, zero-copy per-lane views (per-lane device slicing would
         # dispatch a device op per request, the exact fixed cost this
         # path amortizes); blocking on chunk k overlaps chunk k+1's
         # in-flight compute
         results: list = [None] * len(reqs)
-        for cpos, take, outs in pending:
-            hosts = {k: np.asarray(v) for k, v in outs.items()}
-            for j in range(take):
-                results[cpos + j] = {k: h[j] for k, h in hosts.items()}
+        with tracing.span("aeg.readback"):
+            for cpos, take, outs in pending:
+                hosts = {k: np.asarray(v) for k, v in outs.items()}
+                for j in range(take):
+                    results[cpos + j] = {k: h[j] for k, h in hosts.items()}
         return results
 
     # --------------------------------------------------------- partitioned

@@ -149,7 +149,6 @@ class LinkedProgram:
     names: list                    # slot index -> symbol
     thunks: list                   # thunk(slots, rimfs) -> None
     metas: list                    # list[ThunkMeta], parallel to thunks
-    block_spans: list              # (block_id, thunk_start, thunk_end)
     input_slots: dict              # input symbol -> slot
     weight_slots: dict             # weight symbol -> slot
     output_slots: tuple            # (symbol, slot) pairs
@@ -325,14 +324,12 @@ def link(bound: rbl_mod.BoundProgram, driver,
 
     thunks: list = []
     metas: list = []
-    block_spans: list = []
     prefetch_entries: list = []                    # (dst_slot, src_slot, sym)
     epilogue: list = []
     n_compute = 0
     free_lists: list = []
     idx = 0                                        # linear op index
     for block in prog.blocks:
-        start = len(thunks)
         for op in block.ops:
             kind = op.op
             frees = tuple(slot_of[s] for s in frees_by_idx[idx])
@@ -518,7 +515,6 @@ def link(bound: rbl_mod.BoundProgram, driver,
             thunks.append(thunk)
             metas.append(ThunkMeta(block.block_id, kind, dslots, op.dsts))
             free_lists.append(frees)
-        block_spans.append((block.block_id, start, len(thunks)))
 
     prologue: list = []
     if prefetch_entries:
@@ -554,6 +550,6 @@ def link(bound: rbl_mod.BoundProgram, driver,
                          if t.kind == "output")
     missing = tuple((n, slot_of[n]) for n in bound.missing_inputs)
     return LinkedProgram(prog, driver, slot_of, names, thunks, metas,
-                         block_spans, input_slots, weight_slots,
+                         input_slots, weight_slots,
                          output_slots, missing, tuple(free_lists),
                          n_compute, plan, tuple(prologue), tuple(epilogue))

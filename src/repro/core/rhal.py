@@ -461,7 +461,13 @@ def make_eager_driver(device: Optional[jax.Device] = None,
             from repro.kernels import registry
             return registry.linked_handler(oplib.OP_KERNELS[op], attrs)
         fn = oplib.lookup(op)
-        return jax.jit(lambda *srcs: fn(srcs, attrs))
+
+        def handler(*srcs):
+            return fn(srcs, attrs)
+        # the program's name in a device trace: jit_rcb_<opcode>
+        handler.__name__ = handler.__qualname__ = \
+            f"rcb_{Op(op).name.lower()}"
+        return jax.jit(handler)
 
     d = HalDriver("eager", alloc, free, bind_const, initiate_dma,
                   wait_dma, dispatch_compute, collective, fence, poll, donate,

@@ -85,7 +85,7 @@ class EventDispatcher:
 class Telemetry:
     def __init__(self, capacity: int = 65536):
         self._lat: collections.deque = collections.deque(maxlen=capacity)
-        self._metrics: collections.deque = collections.deque(maxlen=capacity)
+        self._seen = 0                  # samples ever recorded
         self._lock = threading.Lock()
         self.bytes_moved = 0
         self.bytes_overlapped = 0
@@ -106,14 +106,17 @@ class Telemetry:
             return dict(self._counters)
 
     def record_latency(self, seconds: float) -> None:
-        self._lat.append(seconds)
+        with self._lock:
+            self._lat.append(seconds)
+            self._seen += 1
 
     def count(self) -> int:
-        """Samples recorded so far (ring-capped). With ``summary(warmup=
-        prev_count)`` this gives windowed stats over only the samples
-        that landed since a controller's previous observation — the
-        brown-out ladder's queue-wait p99 signal."""
-        return len(self._lat)
+        """Samples recorded so far: a sequence number that keeps counting
+        once the ring is full. With ``summary(warmup=prev_count)`` this
+        gives windowed stats over only the samples that landed since a
+        controller's previous observation — the brown-out ladder's
+        queue-wait p99 signal."""
+        return self._seen
 
     def record_dma(self, bytes_moved: int, bytes_overlapped: int = 0) -> None:
         """Data-movement accounting from the residency plan: total DMA
@@ -128,11 +131,13 @@ class Telemetry:
         return {"bytes_moved": moved, "bytes_overlapped": over,
                 "overlap_fraction": over / moved if moved else 0.0}
 
-    def record(self, **metrics) -> None:
-        self._metrics.append(dict(metrics, t=time.time()))
-
     def summary(self, warmup: int = 0) -> dict:
-        xs = list(self._lat)[warmup:]
+        """Stats of the samples numbered ``warmup`` onwards (the first
+        sample ever recorded is number 0) that the ring still holds."""
+        with self._lock:
+            xs = list(self._lat)
+            first = self._seen - len(xs)        # number of xs[0]
+        xs = xs[max(0, warmup - first):]
         if len(xs) < 2:
             return {"n": len(xs)}
         xs_sorted = sorted(xs)
@@ -377,8 +382,10 @@ class ServiceLoop:
         self._thread.start()
 
     # ------------------------------------------------------------- producers
-    def submit(self, item: Any) -> bool:
+    def submit(self, item: Any, timed: bool = True) -> bool:
         """Enqueue from any thread. False == rejected (backpressure).
+        ``timed=False`` leaves the item's wait in the queue out of
+        ``queue_wait``: a wake-up whose caller records its own wait.
 
         The drain-check + put happen under ``_submit_lock`` — ``close``
         sets the draining flag under the same lock, so an accepted item
@@ -387,7 +394,8 @@ class ServiceLoop:
         with self._submit_lock:
             if not self._draining.is_set():
                 try:
-                    self._q.put_nowait((time.monotonic(), item))
+                    self._q.put_nowait(
+                        (time.monotonic() if timed else None, item))
                     return True
                 except queue_mod.Full:
                     pass
@@ -441,7 +449,8 @@ class ServiceLoop:
             t_enq, item = got
             self._step += 1
             hb.beat(self.name, self._step)
-            self.queue_wait.record_latency(time.monotonic() - t_enq)
+            if t_enq is not None:
+                self.queue_wait.record_latency(time.monotonic() - t_enq)
             self._current = item
             if self.watchdog is not None:
                 self.watchdog.arm(item)
@@ -543,8 +552,6 @@ class Platform:
         self.rimfs: Optional[rimfs_mod.RIMFS] = None
         self.program: Optional[RCBProgram] = None
         self._ready_at: Optional[float] = None
-        self.events.register("rcb_complete",
-                             lambda p: self.telemetry.record(**p))
         self.events.register(
             "dma_complete",
             lambda p: self.telemetry.record_dma(

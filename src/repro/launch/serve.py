@@ -128,11 +128,11 @@ def serve_resnet(cfg: ResNetConfig, requests: int, batch: int, clients: int,
         buckets = server.warm()
         print(f"[serve] set-up {time.perf_counter() - t0:.1f}s "
               f"(batch buckets {buckets} compiled)")
-        t0 = time.perf_counter()
+        t0, since = time.perf_counter(), time.perf_counter_ns()
         n = len(drive_resnet(server.address, cfg, batch, requests, clients,
                              pipeline, 1))
         dt = time.perf_counter() - t0
-        tel = c0.telemetry()
+        tel = c0.telemetry(since_ns=since)      # stages of the driven load
         srv = tel.get("serving", {})
         print(f"[serve] {n} requests x batch {batch} over {clients} "
               f"client(s) (pipeline depth {pipeline}): "
@@ -145,6 +145,9 @@ def serve_resnet(cfg: ResNetConfig, requests: int, batch: int, clients: int,
               f"{srv.get('batched', {}).get('dispatches', 0)}dispatches "
               f"queue_wait_p95="
               f"{srv.get('queue_wait', {}).get('p95', 0)*1e3:.2f}ms")
+        stages = " ".join(f"{k}={v['p95']*1e3:.2f}ms"
+                          for k, v in tel.get("stages", {}).items())
+        print(f"[serve] stage p95: {stages}")
         c0.close()
     finally:
         server.stop()

@@ -172,11 +172,20 @@ def _recv_exact(sock: socket.socket, n: int) -> bytes:
     return b"".join(chunks)
 
 
-def recv_frame_ex(sock: socket.socket,
-                  max_frame: Optional[int] = None) -> Frame:
-    """Receive one frame (either version). The length cap is enforced
-    before the payload is read — a hostile length field never triggers a
-    multi-GiB allocation."""
+class Head(NamedTuple):
+    """A frame's header, read ahead of its body."""
+    kind: "Msg"
+    length: int                # payload bytes
+    request_id: int
+    flags: int
+    version: int
+    raw: bytes                 # the header's bytes, which the CRC covers
+
+
+def recv_head(sock: socket.socket, max_frame: Optional[int] = None) -> Head:
+    """Receive one frame's header (either version); blocks until it has
+    arrived. The length cap is enforced here, before any payload is read —
+    a hostile length field never triggers a multi-GiB allocation."""
     head = _recv_exact(sock, HEADER.size)
     magic, raw_kind, n = HEADER.unpack(head)
     if magic != MAGIC:
@@ -189,12 +198,24 @@ def recv_frame_ex(sock: socket.socket,
         ext = _recv_exact(sock, EXT.size)
         rid, flags = EXT.unpack(ext)
         head += ext
-    rest = _recv_exact(sock, n + 4)
-    payload = rest[:n]
-    (crc,) = struct.unpack_from("<I", rest, n)
-    if crc != (zlib.crc32(head + payload) & 0xFFFFFFFF):
+    return Head(kind, n, rid, flags, version, head)
+
+
+def recv_body(sock: socket.socket, head: Head) -> Frame:
+    """Receive the payload and CRC that follow ``head`` and check them."""
+    rest = _recv_exact(sock, head.length + 4)
+    payload = rest[:head.length]
+    (crc,) = struct.unpack_from("<I", rest, head.length)
+    if crc != (zlib.crc32(head.raw + payload) & 0xFFFFFFFF):
         raise ProtocolError("frame CRC mismatch")
-    return Frame(kind, payload, rid, flags, version)
+    return Frame(head.kind, payload, head.request_id, head.flags,
+                 head.version)
+
+
+def recv_frame_ex(sock: socket.socket,
+                  max_frame: Optional[int] = None) -> Frame:
+    """Receive one frame (either version)."""
+    return recv_body(sock, recv_head(sock, max_frame=max_frame))
 
 
 def recv_frame(sock: socket.socket, max_frame: Optional[int] = None) -> tuple:

@@ -28,6 +28,10 @@ Flow per request:
                    (pumped between queue pops via the loop's on_idle
                    hook; replies routed back by request id)
   SHUTDOWN:        graceful drain — queued work is answered, then stop.
+
+Each stage of a plain RCB request is a span in ``core.tracing`` (recv,
+unpack, wait, dispatch with issue and readback, reply; DESIGN.md §15); the
+TELEMETRY reply summarises them under ``"stages"``.
 """
 from __future__ import annotations
 
@@ -39,11 +43,12 @@ import socket
 import struct
 import threading
 import time
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
 from repro.core import linker as linker_mod
+from repro.core import tracing
 from repro.core.executor import Executor
 from repro.core.integrity import IntegrityError
 from repro.core.rhal import TileFailure
@@ -158,6 +163,16 @@ class _Work:
 _KICK = _Work(frame=None, route=None)   # wake the dispatcher to drain
 
 
+class _Plain(NamedTuple):
+    """A plain-RCB INFER waiting in the scheduler (its payload)."""
+    route: _Route
+    rid: int                            # the wire's request id
+    ver: int                            # the wire's protocol version
+    tensors: dict
+    req: int                            # process-unique id of its spans
+    t_submit: int                       # perf_counter_ns at submit
+
+
 class InferenceServer:
     def __init__(self, host: str = "127.0.0.1", port: int = 0,
                  artifacts: Optional[dict] = None, engine=None, mesh=None,
@@ -244,9 +259,9 @@ class InferenceServer:
                 # only stragglers that raced the dispatcher's exit
                 payload = proto.pack_json({"error": "draining"})
                 for s in self.scheduler.drain_pending():
-                    r, srid, sver, _ = s.payload
-                    r.send(proto.Msg.ERROR, payload, rid=srid,
-                           flags=proto.F_DRAINING, version=sver)
+                    p = s.payload
+                    p.route.send(proto.Msg.ERROR, payload, rid=p.rid,
+                                 flags=proto.F_DRAINING, version=p.ver)
                 if not self._loop.alive():
                     # only touch dispatcher-owned state once the worker is
                     # really gone (a wedged worker may still resume)
@@ -285,7 +300,10 @@ class InferenceServer:
     def _pump_frames(self, conn: socket.socket, route: _Route) -> None:
         while not self._stop.is_set():
             try:
-                frame = proto.recv_frame_ex(conn, max_frame=self.max_frame)
+                head = proto.recv_head(conn, max_frame=self.max_frame)
+                t_head = time.perf_counter_ns()
+                frame = proto.recv_body(conn, head)
+                t_body = time.perf_counter_ns()
             except (ConnectionError, OSError):
                 return
             except proto.ProtocolError as e:
@@ -309,7 +327,12 @@ class InferenceServer:
                     self.stop(drain=True)       # graceful: queued work runs
                     return
                 elif frame.kind == proto.Msg.INFER_REQUEST:
-                    self._enqueue_infer(frame, route)
+                    req = tracing.new_id()
+                    tracing.record("aeg.recv", t_head, t_body, req=req,
+                                   rid=frame.request_id,
+                                   bytes=len(frame.payload))
+                    with tracing.span("aeg.unpack", req=req):
+                        self._enqueue_infer(frame, route, req)
                 elif not self._loop.submit(_Work(frame, route)):
                     flags = proto.F_DRAINING if self._stop.is_set() \
                         else proto.F_BUSY
@@ -324,7 +347,8 @@ class InferenceServer:
                            proto.pack_json({"error": str(e)}),
                            rid=frame.request_id, version=frame.version)
 
-    def _enqueue_infer(self, frame: proto.Frame, route: _Route) -> None:
+    def _enqueue_infer(self, frame: proto.Frame, route: _Route,
+                       req: int) -> None:
         """Handler-thread half of an INFER_REQUEST: parse the npz +
         admission metadata, then either enqueue a ScheduledRequest (plain
         RCB — deadline anchored NOW, so dispatch-queue wait counts
@@ -362,7 +386,9 @@ class InferenceServer:
         # the kick-lands-first race); a refused kick means the dispatcher
         # is full or draining, so the request is refused too — never
         # parked where nothing will ever answer it
-        if not self._loop.submit(_KICK):
+        # (a kick's own wait in the loop queue is not timed: the request's
+        # wait is, from here to its admission)
+        if not self._loop.submit(_KICK, timed=False):
             flags = proto.F_DRAINING if self._stop.is_set() \
                 else proto.F_BUSY
             route.send(proto.Msg.ERROR,
@@ -371,7 +397,8 @@ class InferenceServer:
             return
         self.scheduler.submit(ScheduledRequest(
             rid=rid, tokens_needed=1, priority=priority, deadline=deadline,
-            payload=(route, rid, ver, tensors)))
+            payload=_Plain(route, rid, ver, tensors, req,
+                           time.perf_counter_ns())))
 
     # ------------------------------------------------------------ watchdog
     def _watchdog_budget(self, token: Any) -> Optional[float]:
@@ -452,8 +479,11 @@ class InferenceServer:
             elif frame.kind == proto.Msg.INFER_REQUEST:
                 self._infer_lm(work)
             elif frame.kind == proto.Msg.TELEMETRY:
+                span = proto.unpack_json(frame.payload) \
+                    if frame.payload else {}
                 route.send(proto.Msg.TELEMETRY,
-                           proto.pack_json(self._telemetry_summary()),
+                           proto.pack_json(self._telemetry_summary(
+                               span.get("since_ns"), span.get("until_ns"))),
                            rid=rid, version=ver)
             else:
                 raise RuntimeError(f"unexpected message {frame.kind!r}")
@@ -496,13 +526,21 @@ class InferenceServer:
         progressed = False
         while True:
             admitted = self.scheduler.admit(1)
+            self._end_wait(admitted)
             if admitted and self._coalescible():
-                admitted += self.scheduler.admit(self.batch_window - 1)
-            for s in self.scheduler.drain_shed():
-                r, srid, sver, _ = s.payload
-                r.send(proto.Msg.ERROR,
-                       self._shed_payload(s.verdict_kind, s.verdict),
-                       rid=srid, flags=proto.F_SHED, version=sver)
+                more = self.scheduler.admit(self.batch_window - 1)
+                self._end_wait(more)
+                admitted += more
+            shed = self.scheduler.drain_shed()
+            self._end_wait(shed)
+            for s in shed:
+                p = s.payload
+                with tracing.span("aeg.reply", req=p.req):
+                    p.route.send(proto.Msg.ERROR,
+                                 self._shed_payload(s.verdict_kind,
+                                                    s.verdict),
+                                 rid=p.rid, flags=proto.F_SHED,
+                                 version=p.ver)
                 progressed = True
             if not admitted:
                 return progressed
@@ -510,7 +548,7 @@ class InferenceServer:
             # (EDF order preserved across runs)
             runs: list = []
             for s in admitted:
-                sig = self._tensor_sig(s.payload[3])
+                sig = self._tensor_sig(s.payload.tensors)
                 if runs and runs[-1][0] == sig:
                     runs[-1][1].append(s)
                 else:
@@ -521,6 +559,28 @@ class InferenceServer:
                 else:
                     self._dispatch_batch(run)
                 progressed = True
+
+    def _end_wait(self, popped: list) -> None:
+        """Close the ``aeg.wait`` span of requests the scheduler just
+        popped; their waits are the loop's ``queue_wait`` samples."""
+        if not popped:
+            return
+        t = time.perf_counter_ns()
+        for s in popped:
+            p = s.payload
+            tracing.record("aeg.wait", p.t_submit, t, req=p.req)
+            self._loop.queue_wait.record_latency((t - p.t_submit) / 1e9)
+
+    def _reply(self, s, kind: proto.Msg, payload: bytes = b"",
+               flags: int = 0, tensors: Optional[dict] = None) -> None:
+        """The one terminal reply to the plain request ``s``; ``tensors``
+        are packed into the payload inside its ``aeg.reply`` span."""
+        p = s.payload
+        with tracing.span("aeg.reply", req=p.req):
+            if tensors is not None:
+                payload = proto.pack_tensors(tensors)
+            p.route.send_final(s, kind, payload, rid=p.rid, version=p.ver,
+                               flags=flags)
 
     def _execute_request(self, tensors: dict, rid: int) -> tuple:
         """One plain-RCB execution, canary-aware. Returns (out, flags).
@@ -550,31 +610,32 @@ class InferenceServer:
         return shadow_out, proto.F_CANARY
 
     def _dispatch_single(self, s) -> None:
-        r, srid, sver, sts = s.payload
+        p = s.payload
         wd = self._loop.watchdog
         self._executing = s
         t0 = time.perf_counter()
         try:
-            if wd is not None:
-                wd.arm(s)
-            try:
-                out, oflags = self._execute_request(sts, srid)
-            except (TileFailure, IntegrityError) as e:
-                # recoverable fault taxonomy (DESIGN.md §11): one re-run
-                # on healthy resources — the dead group is excluded by
-                # the partition failover, a corrupted transfer re-issues
-                # from its retained source
-                kind = "integrity_error" if isinstance(e, IntegrityError) \
-                    else "tile_failure"
-                self.platform.post(kind, {"stage": "dispatch",
-                                          "error": str(e)})
+            with tracing.span("aeg.dispatch", req=p.req, mode="solo", n=1,
+                              reqs=(p.req,)):
                 if wd is not None:
-                    wd.arm(s)           # fresh budget for the re-run
-                out, oflags = self._execute_request(sts, srid)
+                    wd.arm(s)
+                try:
+                    out, oflags = self._execute_request(p.tensors, p.rid)
+                except (TileFailure, IntegrityError) as e:
+                    # recoverable fault taxonomy (DESIGN.md §11): one
+                    # re-run on healthy resources — the dead group is
+                    # excluded by the partition failover, a corrupted
+                    # transfer re-issues from its retained source
+                    kind = "integrity_error" \
+                        if isinstance(e, IntegrityError) else "tile_failure"
+                    self.platform.post(kind, {"stage": "dispatch",
+                                              "error": str(e)})
+                    if wd is not None:
+                        wd.arm(s)       # fresh budget for the re-run
+                    out, oflags = self._execute_request(p.tensors, p.rid)
         except Exception as e:                  # report, keep draining
-            r.send_final(s, proto.Msg.ERROR,
-                         proto.pack_json({"error": str(e)}),
-                         rid=srid, version=sver)
+            self._reply(s, proto.Msg.ERROR,
+                        proto.pack_json({"error": str(e)}))
             return
         finally:
             if wd is not None:
@@ -583,8 +644,7 @@ class InferenceServer:
         dt = time.perf_counter() - t0
         self.platform.telemetry.record_latency(dt)
         self.scheduler.observe_step_latency(dt)
-        r.send_final(s, proto.Msg.INFER_RESPONSE, proto.pack_tensors(out),
-                     rid=srid, version=sver, flags=oflags)
+        self._reply(s, proto.Msg.INFER_RESPONSE, flags=oflags, tensors=out)
 
     def _dispatch_batch(self, run: list) -> None:
         """One coalesced dispatch for a same-signature request run.
@@ -597,25 +657,25 @@ class InferenceServer:
         actually experiences, and shed feasible work."""
         if self._bound is None:
             for s in run:                       # mirror _infer's refusal
-                r, srid, sver, _ = s.payload
-                r.send_final(s, proto.Msg.ERROR,
-                             proto.pack_json({"error": "not provisioned"}),
-                             rid=srid, version=sver)
+                self._reply(s, proto.Msg.ERROR,
+                            proto.pack_json({"error": "not provisioned"}))
             return
         wd = self._loop.watchdog
         self._executing = run
         try:
-            # a cold bucket compiles here, before the watchdog is armed:
-            # compiling is set-up, not a hung execution
-            self.executor.prepare_batched(self._bound, len(run))
-            t0 = time.perf_counter()
-            if wd is not None:
-                wd.arm(run)
-            outs = self.executor.run_batched(
-                self._bound, [s.payload[3] for s in run],
-                rimfs=self.platform.rimfs)
-            outs = [{k: np.asarray(v) for k, v in out.items()}
-                    for out in outs]
+            with tracing.span("aeg.dispatch", mode="batched", n=len(run),
+                              reqs=tuple(s.payload.req for s in run)):
+                # a cold bucket compiles here, before the watchdog is
+                # armed: compiling is set-up, not a hung execution
+                self.executor.prepare_batched(self._bound, len(run))
+                t0 = time.perf_counter()
+                if wd is not None:
+                    wd.arm(run)
+                outs = self.executor.run_batched(
+                    self._bound, [s.payload.tensors for s in run],
+                    rimfs=self.platform.rimfs)
+                outs = [{k: np.asarray(v) for k, v in out.items()}
+                        for out in outs]
         except Exception:
             # fault isolation: a failed batched dispatch (e.g. the wider
             # batch shape fails to stage) must not take down requests
@@ -635,11 +695,9 @@ class InferenceServer:
         st["requests"] += len(run)
         st["max_batch"] = max(st["max_batch"], len(run))
         for s, out in zip(run, outs):
-            r, srid, sver, _ = s.payload
             self.platform.telemetry.record_latency(amortized)
             self.scheduler.observe_step_latency(amortized)
-            r.send_final(s, proto.Msg.INFER_RESPONSE,
-                         proto.pack_tensors(out), rid=srid, version=sver)
+            self._reply(s, proto.Msg.INFER_RESPONSE, tensors=out)
 
     def _infer_lm(self, work: _Work) -> None:
         """LM service program: continuous batching via the engine; the
@@ -708,9 +766,9 @@ class InferenceServer:
         payload = proto.pack_json({"error": "preempted: dispatcher "
                                    "closing"})
         for s in (ex if isinstance(ex, list) else [ex]):
-            r, srid, sver, _ = s.payload
-            r.send_final(s, proto.Msg.ERROR, payload, rid=srid,
-                         flags=proto.F_DRAINING, version=sver)
+            p = s.payload
+            p.route.send_final(s, proto.Msg.ERROR, payload, rid=p.rid,
+                               flags=proto.F_DRAINING, version=p.ver)
 
     def run_on_dispatcher(self, fn, timeout: float = 60.0):
         """Execute ``fn`` ON the dispatcher thread and return its result.
@@ -803,7 +861,8 @@ class InferenceServer:
                            rid=rid, version=ver)
         return bool(self._inflight)
 
-    def _telemetry_summary(self) -> dict:
+    def _telemetry_summary(self, since_ns: Optional[int] = None,
+                           until_ns: Optional[int] = None) -> dict:
         s = dict(self.platform.telemetry.summary(warmup=1))
         shed = self.scheduler.shed_count
         if self.engine is not None and self.engine.scheduler is not None:
@@ -812,6 +871,7 @@ class InferenceServer:
                         "inflight": len(self._inflight),
                         "batched": dict(self.batched_stats)}
         s["counters"] = self.platform.telemetry.counters()
+        s["stages"] = tracing.stage_summary(since_ns, until_ns)
         if self.engine is not None:
             s["engine"] = self.engine.telemetry.summary(warmup=1)
             if hasattr(self.engine, "kv_stats"):
@@ -843,7 +903,8 @@ class InferenceServer:
                 mesh=self.mesh, platform=self.platform)
         else:
             out = self.executor.run(bound, inputs=tensors, rimfs=fs)
-        return {k: np.asarray(v) for k, v in out.items()}
+        with tracing.span("aeg.readback"):
+            return {k: np.asarray(v) for k, v in out.items()}
 
 
 # ------------------------------------------------------------------ client
@@ -1049,8 +1110,17 @@ class Client:
                 attempt += 1
                 self.retry_stats["retries"] += 1
 
-    def telemetry(self) -> dict:
-        return proto.unpack_json(self._rpc(proto.Msg.TELEMETRY, b"").payload)
+    def telemetry(self, since_ns: Optional[int] = None,
+                  until_ns: Optional[int] = None) -> dict:
+        """The server's counters and latency summaries. ``stages`` covers
+        the request-stage spans that start in ``[since_ns, until_ns)`` on
+        the server host's ``time.perf_counter_ns()`` clock, all of them by
+        default."""
+        span = {k: v for k, v in (("since_ns", since_ns),
+                                  ("until_ns", until_ns)) if v is not None}
+        return proto.unpack_json(self._rpc(
+            proto.Msg.TELEMETRY,
+            proto.pack_json(span) if span else b"").payload)
 
     def shutdown(self) -> dict:
         """Graceful server drain; returns the server's drain ack."""

@@ -107,6 +107,33 @@ def test_ladder_walks_down_and_back_with_hysteresis(chain_setup):
         server.stop()
 
 
+def test_ladder_reads_fresh_waits_past_ring_capacity(chain_setup):
+    """Once the queue-wait ring is full, each tick still sees the samples
+    since the last one: the ladder descends on hot ones and climbs back
+    on cool ones."""
+    from repro.core.rtpm import Telemetry
+    prog, files, image = chain_setup
+    server, addr, client = _start(prog, image)
+    try:
+        server._loop.queue_wait = Telemetry(capacity=4)
+        _heat(server, 8, 0.001)                 # the ring is full
+        cfg = OverloadConfig(p99_high=0.1, min_window=2, escalate_ticks=1,
+                             recover_ticks=1)
+        over = BrownoutController(server, cfg)
+        for _ in range(3):
+            _heat(server, 3, 0.4)
+            over.tick()
+        assert over.rung == 3
+        # three requests, and the wait of the last tick's control op
+        assert over.history[-1]["obs"]["window"] >= 3
+        _heat(server, 3, 0.001)
+        over.tick()
+        assert over.rung == 2
+    finally:
+        client.close()
+        server.stop()
+
+
 # ----------------------------------------------------------- typed sheds
 def test_rung3_sheds_low_priority_with_typed_verdict(chain_setup):
     """At rung 3, admissions at or past the priority ceiling get an

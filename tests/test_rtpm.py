@@ -67,6 +67,25 @@ def test_telemetry_cv():
     assert s["p99"] >= s["p50"] >= s["min"]
 
 
+def test_telemetry_windows_past_capacity():
+    """``count()`` numbers every sample, and ``summary(warmup=seen)``
+    covers the samples since ``seen`` that the ring still holds, also
+    after it wrapped."""
+    tel = Telemetry(capacity=4)
+    for i in range(10):
+        tel.record_latency(float(i))
+    assert tel.count() == 10
+    s = tel.summary(warmup=8)
+    assert s["n"] == 2 and (s["min"], s["max"]) == (8.0, 9.0)
+    s = tel.summary(warmup=3)            # 3..5 fell off the ring
+    assert s["n"] == 4 and s["min"] == 6.0
+    assert tel.summary(warmup=10) == {"n": 0}
+    tel.record_latency(10.0)
+    tel.record_latency(11.0)
+    s = tel.summary(warmup=10)
+    assert s["n"] == 2 and s["mean"] == 10.5
+
+
 def test_platform_provision_bind_run(rng):
     """The paper's 4-phase flow end to end through the Platform."""
     prog = rctc.compile_matmul(16)

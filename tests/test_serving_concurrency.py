@@ -536,6 +536,7 @@ def test_forced_stop_refuses_pending_admissions(resnet_setup):
     """stop(drain=False) still answers every accepted request: pending
     admissions get ERROR/F_DRAINING instead of a silent drop."""
     from repro.serving.scheduler import ScheduledRequest
+    from repro.serving.server import _Plain
 
     cfg, prog, image = resnet_setup
     server, addr, client = _start(prog, image)
@@ -549,7 +550,8 @@ def test_forced_stop_refuses_pending_admissions(resnet_setup):
 
         server._loop.close(drain=True)           # park the dispatcher
         server.scheduler.submit(ScheduledRequest(
-            rid=77, tokens_needed=1, payload=(StubRoute(), 77, 2, {})))
+            rid=77, tokens_needed=1,
+            payload=_Plain(StubRoute(), 77, 2, {}, req=0, t_submit=0)))
         server.stop(drain=False)
         assert sent == [(proto.Msg.ERROR, proto.F_DRAINING, 77)]
     finally:
