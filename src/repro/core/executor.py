@@ -166,7 +166,10 @@ class Executor:
 
         The thunks are issued with no host sync of their own (the
         ``aeg.issue`` span, ``core/tracing.py``); the program's FENCE ops
-        and the caller's readback wait for the device.
+        and the caller's readback wait for the device. The span's
+        ``weight_h2d_bytes`` are the bound weights held in host memory,
+        which the handlers copy to the device on every execution: 0 for a
+        bind against a driver (``rbl.bind(driver=...)``).
         """
         if trace_ops:
             return self.run_interpreted(bound, inputs=inputs, rimfs=rimfs,
@@ -186,7 +189,8 @@ class Executor:
             for i, buf in enumerate(slots):
                 if buf is not None:
                     _probe_update(probe_dev, linked.names[i], buf)
-        with tracing.span("aeg.issue", thunks=len(linked.thunks)):
+        with tracing.span("aeg.issue", thunks=len(linked.thunks),
+                          weight_h2d_bytes=linked.weight_h2d_bytes):
             for pre in linked.prologue:            # prefetch issue phase
                 pre(slots, rimfs)
             if probe_dev is None:
@@ -432,12 +436,13 @@ class Executor:
                     for req in reqs]
         prep = getattr(bound, "_batch_prep", None)
         if prep is None or prep[0] is not bound.program:
+            weights = self.weights_from(bound)
             prep = bound._batch_prep = (
                 bound.program,
                 tuple(n for n, t in bound.program.tensors.items()
                       if t.kind == "input"),
-                self.weights_from(bound))
-        _, input_syms, weights = prep
+                weights, linker_mod.host_bytes(weights.values()))
+        _, input_syms, weights, weight_h2d = prep
         # phase 1: stack + dispatch every chunk (no sync anywhere)
         pending: list = []                 # (pos, take, {sym: device out})
         pos = 0
@@ -463,6 +468,8 @@ class Executor:
                 self.batch_stats["padded"] += bucket - take
                 pos += take
             issue.stats["thunks"] = len(pending)
+            # each chunk's call copies the host-resident weights over
+            issue.stats["weight_h2d_bytes"] = weight_h2d * len(pending)
         # phase 2: materialize in order — ONE d2h per output tensor per
         # chunk, zero-copy per-lane views (per-lane device slicing would
         # dispatch a device op per request, the exact fixed cost this

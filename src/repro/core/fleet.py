@@ -746,7 +746,7 @@ class FleetController:
 
             server.run_on_dispatcher(flip_back,
                                      timeout=self.cfg.control_timeout)
-            self._release_residency(swap.new_rimfs)
+            swap.new_rimfs.release()
             self._swap = None
             self._post("swap_rolled_back", {"reason": reason})
 
@@ -760,7 +760,7 @@ class FleetController:
             freed = 0
             if self.cfg.finalize_unpin and \
                     swap.old_rimfs is not swap.new_rimfs:
-                freed = self._release_residency(swap.old_rimfs)
+                freed = swap.old_rimfs.release()
             self._swap = None
             self._post("swap_finalized", {"freed_bytes": freed})
 
@@ -861,7 +861,7 @@ class FleetController:
                 flip, timeout=self.cfg.control_timeout)
             freed = 0
             if self.cfg.finalize_unpin and old_fs is not state.fs:
-                freed = self._release_residency(old_fs)
+                freed = old_fs.release()
             self._canary = None
             self._post("canary_promoted",
                        dict(state.sprt.summary(), label=state.label,
@@ -883,24 +883,11 @@ class FleetController:
 
             server.run_on_dispatcher(clear,
                                      timeout=self.cfg.control_timeout)
-            self._release_residency(state.fs)
+            state.fs.release()
             self._canary = None
             self._post("canary_aborted",
                        dict(state.sprt.summary(), label=state.label,
                             stats=dict(state.stats), reason=reason))
-
-    @staticmethod
-    def _release_residency(fs) -> int:
-        """Unpin every driver's resident copy of ``fs`` (arena ranges
-        freed; the RIMFS host image itself is untouched)."""
-        if fs is None:
-            return 0
-        freed = 0
-        for _key, (_ref, ri) in list(fs._resident.items()):
-            freed += ri.nbytes()
-            ri.unpin()
-        fs._resident.clear()
-        return freed
 
     # ----------------------------------------------------------- lifecycle
     def start(self, interval: float = 0.2) -> None:

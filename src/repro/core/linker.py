@@ -34,6 +34,8 @@ import dataclasses
 import json
 from typing import Any, Callable, Optional
 
+import jax
+
 from repro.core import rbl as rbl_mod
 from repro.core.rcb import Op, RCBProgram
 from repro.core.rhal import (ARENA_ALIGN, DeviceArena, DmaTicket, _nbytes_of)
@@ -158,6 +160,7 @@ class LinkedProgram:
     residency: Optional[ResidencyPlan] = None
     prologue: tuple = ()           # prefetch issue thunks (run before thunks)
     epilogue: tuple = ()           # drain redeem thunks (run after thunks)
+    weight_h2d_bytes: int = 0      # host-resident weight bytes per execution
 
     @property
     def n_slots(self) -> int:
@@ -176,6 +179,13 @@ class LinkedProgram:
                 if i is not None:
                     slots[i] = buf
         return slots
+
+
+def host_bytes(buffers) -> int:
+    """Bytes of ``buffers`` held in host memory: anything but a
+    ``jax.Array``, which a device computation copies over on every call."""
+    return sum(int(b.nbytes) for b in buffers
+               if not isinstance(b, jax.Array))
 
 
 def _mk_compute(handler: Callable, d: int, src_idx: tuple, frees: tuple):
@@ -552,4 +562,5 @@ def link(bound: rbl_mod.BoundProgram, driver,
     return LinkedProgram(prog, driver, slot_of, names, thunks, metas,
                          input_slots, weight_slots,
                          output_slots, missing, tuple(free_lists),
-                         n_compute, plan, tuple(prologue), tuple(epilogue))
+                         n_compute, plan, tuple(prologue), tuple(epilogue),
+                         host_bytes(bound.buffers[n] for n in weight_slots))

@@ -255,6 +255,17 @@ class RIMFS:
         self._resident[id(driver)] = (weakref.ref(driver), ri)
         return ri
 
+    def release(self) -> int:
+        """Unpin every driver's resident copy of this image (arena ranges
+        freed; the host image itself is untouched); returns the bytes
+        released."""
+        freed = 0
+        for _ref, ri in list(self._resident.values()):
+            freed += ri.nbytes()
+            ri.unpin()
+        self._resident.clear()
+        return freed
+
     def total_bytes(self) -> int:
         return len(self._data)
 

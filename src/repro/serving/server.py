@@ -885,10 +885,18 @@ class InferenceServer:
         k1, image = proto.decode_frame(payload, max_frame=self.max_frame)
         rest = payload[proto.HEADER.size + len(image) + 4:]
         k2, prog = proto.decode_frame(rest, max_frame=self.max_frame)
+        old_fs = self.platform.rimfs
         self.platform.provision(image=image, program_bytes=prog)
         if self.artifacts:
             self.platform.program.artifacts.update(self.artifacts)
-        self._bound = self.platform.bind()
+        # Pin the weights on the device once, against the driver every
+        # request runs on, so no request uploads them again. A mesh binds
+        # each tile group against its own driver (core/partition.py), so
+        # its primary binding stays a host view.
+        self._bound = self.platform.bind(
+            driver=self.executor.driver if self.mesh is None else None)
+        if old_fs is not None:
+            old_fs.release()            # the image this one replaces
 
     def _infer(self, tensors: dict, bound=None, fs=None) -> dict:
         """Execute on the primary binding, or — when the fleet layer

@@ -1,4 +1,6 @@
 """Serving: wire protocol, socket server, LM engine with batched requests."""
+import time
+
 import numpy as np
 import pytest
 
@@ -6,7 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core import rctc, rhal, rimfs
+from repro.core import rbl, rctc, rhal, rimfs, tracing
+from repro.core.executor import Executor
 from repro.models import resnet as rn
 from repro.models import transformer as tf
 from repro.models.common import init_params
@@ -15,6 +18,8 @@ from repro.serving.engine import (Request, ServingEngine, pack_params_image,
                                   params_from_rimfs)
 from repro.serving.scheduler import DeadlineScheduler
 from repro.serving.server import Client, InferenceServer
+
+from test_tracing import _gate_dispatcher
 
 
 def test_frame_roundtrip():
@@ -284,3 +289,131 @@ def test_lm_engine_matches_offline_decode(rng):
         out.append(t)
         toks.append(t)
     assert req.out_tokens[:4] == out
+
+
+# ------------------------------------------------- plain-RCB weight residency
+CHAIN_DEPTH, CHAIN_N = 4, 16
+
+
+@pytest.fixture(scope="module")
+def chain():
+    prog = rctc.compile_gemm_chain(CHAIN_DEPTH, CHAIN_N)
+    files = rctc.gemm_chain_weights(CHAIN_DEPTH, CHAIN_N)
+    return prog, files, rimfs.pack(files)
+
+
+def _chain_x(seed: int) -> np.ndarray:
+    return np.random.RandomState(seed).randn(CHAIN_N, CHAIN_N) \
+        .astype(np.float32)
+
+
+def _serve(chain, **kw):
+    prog, _, image = chain
+    server = InferenceServer(**kw)
+    client = Client(server.start())
+    client.provision(image, prog.encode())
+    return server, client
+
+
+def _infer_coalesced(server, client, xs) -> list:
+    """Send ``xs`` while the dispatcher is held, so they ride one batched
+    dispatch; returns the replies in order."""
+    inner, idle = server._loop.handler, server._loop.on_idle
+    gate, started = _gate_dispatcher(server)
+    before = server.batched_stats["dispatches"]
+    rids = [client.infer_async(input=x) for x in xs]
+    assert started.wait(10)
+    deadline = time.monotonic() + 10
+    while server.scheduler.pending() < len(xs) and \
+            time.monotonic() < deadline:
+        time.sleep(0.005)
+    gate.set()
+    outs = [client.result(rid, timeout=30)["output"] for rid in rids]
+    server._loop.handler, server._loop.on_idle = inner, idle
+    assert server.batched_stats["dispatches"] == before + 1
+    return outs
+
+
+def test_provision_pins_weights_on_the_executor_driver(chain):
+    """PROVISION uploads the program's weights through the executor's
+    driver once; a solo and a batched request then move no weight byte."""
+    _, files, _ = chain
+    server, client = _serve(chain, batch_window=8)
+    try:
+        drv = server.executor.driver
+        weights = {n: b for n, b in server._bound.buffers.items()
+                   if n in files}
+        assert set(weights) == set(files)
+        assert all(isinstance(b, jax.Array) for b in weights.values())
+        assert drv.stats["dma_bytes"] == sum(f.nbytes for f in
+                                             files.values())
+        moved = drv.stats["dma_bytes"]
+        since = time.perf_counter_ns()
+        client.infer(input=_chain_x(0))
+        _infer_coalesced(server, client, [_chain_x(i) for i in (1, 2, 3)])
+        assert drv.stats["dma_bytes"] == moved
+        issues = [s for s in tracing.spans(since) if s.name == "aeg.issue"]
+        assert len(issues) == 2
+        assert [s.stats["weight_h2d_bytes"] for s in issues] == [0, 0]
+    finally:
+        client.close()
+        server.stop()
+
+
+@pytest.mark.parametrize("mode", ["solo", "batched"])
+def test_pinned_replies_match_a_host_view_bind(chain, mode):
+    """The pinned binding serves the bits a host-view bind of the same
+    program computes, through ``run`` and through ``run_batched``."""
+    prog, _, image = chain
+    xs = [_chain_x(10 + i) for i in range(3)]
+    host = rbl.bind(prog, rimfs=rimfs.mount(image))
+    assert not any(isinstance(b, jax.Array) for b in host.buffers.values())
+    server, client = _serve(chain, batch_window=8)
+    try:
+        if mode == "solo":
+            got = [client.infer(input=x)["output"] for x in xs]
+            ref = [np.asarray(Executor().run(host, inputs={"input": x})
+                              ["output"]) for x in xs]
+        else:
+            got = _infer_coalesced(server, client, xs)
+            ref = [o["output"] for o in Executor().run_batched(
+                host, [{"input": x} for x in xs])]
+        for g, r in zip(got, ref):
+            np.testing.assert_array_equal(g, r)
+    finally:
+        client.close()
+        server.stop()
+
+
+def test_tile_mesh_server_keeps_a_host_view_primary_bind(chain):
+    """Over a TileMesh each tile group binds against its own driver, so
+    the primary binding is not pinned a second time."""
+    _, files, _ = chain
+    server, client = _serve(chain, mesh=rhal.TileMesh(2))
+    try:
+        assert all(isinstance(server._bound.buffers[n], np.ndarray)
+                   for n in files)
+        assert server.executor.driver.stats.get("dma_bytes", 0) == 0
+        client.infer(input=_chain_x(0))
+    finally:
+        client.close()
+        server.stop()
+
+
+def test_reprovision_releases_the_replaced_image(chain):
+    """A second PROVISION pins the new image and unpins the one it
+    replaces: the driver's arena holds one image's weights, not two."""
+    prog, files, image = chain
+    server, client = _serve(chain)
+    try:
+        arena = server.executor.driver.arena
+        held = arena.bytes_in_use
+        assert held >= sum(f.nbytes for f in files.values())
+        client.provision(image, prog.encode())
+        assert arena.bytes_in_use == held
+        assert all(isinstance(server._bound.buffers[n], jax.Array)
+                   for n in files)
+        client.infer(input=_chain_x(0))
+    finally:
+        client.close()
+        server.stop()

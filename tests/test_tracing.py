@@ -12,7 +12,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import rctc, rhal, rimfs, tracing
+from repro.core import rbl, rctc, rhal, rimfs, tracing
 from repro.core.executor import Executor
 from repro.core.rcb import Op
 from repro.core.rtpm import Platform
@@ -273,3 +273,27 @@ def test_telemetry_reports_stages(chain):
     finally:
         client.close()
         server.stop()
+
+
+@pytest.mark.parametrize("pinned", [False, True], ids=["host", "pinned"])
+@pytest.mark.parametrize("path", ["run", "run_batched"])
+def test_issue_span_counts_host_weight_bytes(chain, path, pinned):
+    """``aeg.issue`` carries the weight bytes the handlers copy to the
+    device: all of them, once per batched chunk, for a host-view bind;
+    none for a bind against the executor's driver."""
+    prog, files = chain
+    fs = rimfs.mount(rimfs.pack(files))
+    ex = Executor()
+    bound = rbl.bind(prog, rimfs=fs,
+                     driver=ex.driver if pinned else None)
+    xs = [{"input": _x(i)} for i in range(3)]
+    since = time.perf_counter_ns()
+    if path == "run":
+        ex.run(bound, inputs=xs[0], rimfs=fs)
+        calls = 1
+    else:
+        ex.run_batched(bound, xs, rimfs=fs, max_bucket=2)
+        calls = 2                        # chunks of 2 and 1 requests
+    issue, = [s for s in tracing.spans(since) if s.name == "aeg.issue"]
+    want = 0 if pinned else calls * sum(f.nbytes for f in files.values())
+    assert issue.stats["weight_h2d_bytes"] == want
